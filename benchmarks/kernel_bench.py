@@ -27,8 +27,7 @@ from repro.roofline.analysis import HW
 # The codecs with a fused wire scheme, at the fig2 wire shape
 # (batch 1024 rows into the d_fusion=432 fusion layer) plus the two
 # extreme arch d_fusions from repro.configs.
-WIRE_CODECS = ("int8_row", "int4", "topk", "sketch",
-               "ef(int4)", "ef(int8_row)")
+WIRE_CODECS = ("int8_row", "int4", "ef(int4)", "ef(int8_row)")
 FIG2_MKN = (1024, 432, 432)
 
 
@@ -44,7 +43,6 @@ def _measured_bytes(compiled) -> float:
     """'bytes accessed' from cost_analysis, 0.0 when unreported."""
     try:
         ca = compiled.cost_analysis()
-        ca = ca[0] if isinstance(ca, (list, tuple)) else ca
         return float(ca.get("bytes accessed", 0.0) or 0.0)
     except Exception:
         return 0.0

@@ -17,8 +17,7 @@ from typing import Dict, List
 
 DRYRUN = os.path.join(os.path.dirname(__file__), "..", "results", "dryrun")
 
-WIRE_CODECS = ("int8_row", "int4", "topk", "sketch",
-               "ef(int4)", "ef(int8_row)")
+WIRE_CODECS = ("int8_row", "int4", "ef(int4)", "ef(int8_row)")
 
 
 def wire_rows(batch: int = 1024) -> List[Dict]:

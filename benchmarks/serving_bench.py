@@ -241,7 +241,8 @@ def run_load_point(args, store, width: int, n_tenants: int,
 def run(args):
     cfg = smoke_model_config()
     max_t = max(args.tenants)
-    store = build_demo_store(cfg, cfg.name, max_t, seed=args.seed)
+    store = build_demo_store(cfg, cfg.name, max_t, seed=args.seed,
+                             reduced=False)
     cache_len = args.prompt_len + args.gen
 
     if args.autotune:

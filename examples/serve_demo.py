@@ -38,7 +38,7 @@ def main():
                          " (fixed-batch fallback)")
     print(f"== serving {cfg.name}: {args.tenants} tenants, "
           f"lane width {args.width} ==")
-    store = build_demo_store(cfg, args.arch, args.tenants)
+    store = build_demo_store(cfg, args.arch, args.tenants, reduced=True)
     engine = ServeEngine(store, width=args.width,
                          cache_len=args.prompt_len + args.gen,
                          horizon=args.horizon)

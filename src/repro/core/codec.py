@@ -361,16 +361,38 @@ class TopKCodec(Codec):
         return rows * self.k_of(shape[-1]) * (4 + 4)
 
 
+def pack_int4(q: jnp.ndarray) -> jnp.ndarray:
+    """(..., 2h) int values in [-7, 7] -> (..., h) uint8, split-half.
+
+    Byte j holds column j in its low nibble and column j + h in its
+    high nibble, each offset by 8 ([-7, 7] -> [1, 15]). Shared by
+    ``Int4RowCodec`` and the fused Pallas scheme. The arithmetic runs
+    in int32: the TPU's vector unit has no 8-bit integer ops, and
+    interleaved (even/odd column) packing would need a lane reshape
+    the TPU compiler refuses, while two contiguous halves slice
+    cleanly."""
+    u = q.astype(jnp.int32) + 8
+    h = u.shape[-1] // 2
+    return (u[..., :h] | (u[..., h:] << 4)).astype(jnp.uint8)
+
+
+def unpack_int4(packed: jnp.ndarray) -> jnp.ndarray:
+    """Inverse of ``pack_int4``: (..., h) uint8 -> (..., 2h) int32."""
+    p = packed.astype(jnp.int32)
+    return jnp.concatenate([(p & 0xF) - 8, (p >> 4) - 8], axis=-1)
+
+
 @dataclass(frozen=True, repr=False)
 class Int4RowCodec(Codec):
     """Packed symmetric per-row absmax int4: q = round(z / (absmax/7)),
-    clipped to [-7, 7], two nibbles per byte, fp32 scale per row.
+    clipped to [-7, 7], two nibbles per byte (``pack_int4``), fp32
+    scale per row.
 
     ~8x fewer wire bytes than fp32 with one sidecar float per row of the
-    flattened (rows, d_fusion) view. An odd last dim is padded with a
-    zero nibble inside the packed byte — ``encoded_nbytes`` counts
-    ceil(d/2) bytes per row, exactly what ``encode`` emits. Aggressive
-    enough to want error feedback: pair as ``ef(int4)``.
+    flattened (rows, d_fusion) view. An odd last dim is padded with one
+    zero column before packing — ``encoded_nbytes`` counts ceil(d/2)
+    bytes per row, exactly what ``encode`` emits. Aggressive enough to
+    want error feedback: pair as ``ef(int4)``.
     """
 
     name: str = "int4"
@@ -379,23 +401,16 @@ class Int4RowCodec(Codec):
         q, scale = quantize_rows_sym(z, qmax=7)
         if q.shape[-1] % 2:
             pad = [(0, 0)] * (q.ndim - 1) + [(0, 1)]
-            q = jnp.pad(q, pad)  # zero nibble; sliced off on decode
-        u = (q + 8).astype(jnp.uint8)  # [-7,7] -> [1,15]; pad -> 8
-        packed = u[..., 0::2] | (u[..., 1::2] << 4)
-        return {"q4": packed, "scale": scale.astype(jnp.float32)}
+            q = jnp.pad(q, pad)  # zero column; sliced off on decode
+        return {"q4": pack_int4(q), "scale": scale.astype(jnp.float32)}
 
     def decode(self, payload, *, shape=None, dtype=None):
         if shape is None:
             # The packed width is ceil(d/2) bytes — an odd d is
             # indistinguishable from d+1 without the original shape.
             raise ValueError("int4 decode requires the original z shape")
-        packed, scale = payload["q4"], payload["scale"]
-        lo = (packed & jnp.uint8(0xF)).astype(jnp.int32) - 8
-        hi = (packed >> 4).astype(jnp.int32) - 8
-        q = jnp.stack([lo, hi], axis=-1).reshape(
-            *packed.shape[:-1], packed.shape[-1] * 2
-        )
-        z = q[..., : shape[-1]].astype(jnp.float32) * scale
+        q = unpack_int4(payload["q4"])
+        z = q[..., : shape[-1]].astype(jnp.float32) * payload["scale"]
         return z.astype(dtype or jnp.float32)
 
     def encoded_nbytes(self, shape):
@@ -411,10 +426,8 @@ def _sketch_tables(d: int, w: int, seed: int):
     time, so encoder and decoder agree without any index sidecar on the
     wire — the whole point of sketching vs top-k. The bucket counts are
     returned pre-inverted: decode multiplies by 1/count instead of
-    dividing, because the table is a baked constant in the jnp oracle
-    but a runtime input to the fused kernels — XLA folds a constant
-    divisor into a reciprocal-multiply, so only a shared precomputed
-    reciprocal keeps the two paths bitwise equal."""
+    dividing, so eager and jitted decode agree bitwise (XLA folds a
+    constant divisor into a reciprocal-multiply only when jitted)."""
     rng = np.random.default_rng(seed + 1_000_003 * d + w)
     h = rng.integers(0, w, size=d)
     s = (rng.integers(0, 2, size=d) * 2 - 1).astype(np.float32)

@@ -603,6 +603,38 @@ class SPMDFusionExchange(ExchangePlane):
 
     # ------------------------------------------------ in-program wire
 
+    def _fused_encode(self, z, ef_state=None):
+        """The fused Pallas encode of the stacked (N, Bc, ..., d) z ->
+        payload (or (payload, e') given ``ef_state``), or None when the
+        plane is unfused or the codec has no scheme at this width.
+
+        The kernel runs under ``shard_map`` over the mesh: a Mosaic
+        kernel cannot be partitioned automatically, so each device
+        encodes its own clients' rows ('client' x 'data' blocks) and
+        the payload comes out client-sharded, ready for the one
+        all-gather."""
+        inner = getattr(self.codec, "inner", None) or self.codec
+        if not self.fused or wire_fused.scheme_for(inner, z.shape[-1]) is None:
+            return None
+        from jax.sharding import PartitionSpec as P
+
+        interpret = self._fused_interpret
+        if ef_state is None:
+            args = (z,)
+
+            def encode(z):
+                return self.codec.fused_encode(z, interpret=interpret)
+        else:
+            args = (z, ef_state)
+
+            def encode(z, e):
+                return self.codec.fused_encode_with_state(
+                    z, e, interpret=interpret)
+        rows = P("client", "data")
+        return jax.shard_map(encode, mesh=self.mesh,
+                             in_specs=(rows,) * len(args), out_specs=rows,
+                             check_vma=False)(*args)
+
     def wire(self, z, tokens, mask, cache, ef_state):
         """The fusion exchange, traceable inside the jitted round step.
 
@@ -627,9 +659,7 @@ class SPMDFusionExchange(ExchangePlane):
         """
         wire = self.codec
         if wire.has_state:
-            out = (wire.fused_encode_with_state(
-                z, ef_state, interpret=self._fused_interpret)
-                if self.fused else None)
+            out = self._fused_encode(z, ef_state)
             if out is None:
                 out = jax.vmap(wire.encode_with_state)(z, ef_state)
             enc_new, ef_new = out
@@ -637,9 +667,7 @@ class SPMDFusionExchange(ExchangePlane):
                 ef_new = _tree_where(mask, ef_new, ef_state)
             ef_state = jax.tree.map(self._ef_constrain, ef_new)
         else:
-            enc_new = (wire.fused_encode(
-                z, interpret=self._fused_interpret)
-                if self.fused else None)
+            enc_new = self._fused_encode(z)
             if enc_new is None:
                 enc_new = jax.vmap(wire.encode)(z)
         if mask is None:

@@ -133,6 +133,27 @@ def _full_loss_wrt_base(base, mod, cfg: ModelConfig, batch):
 # ------------------------------------------------------------------ round
 
 
+def _client_local(fn, mesh: Mesh, n_client: int, n_shared: int = 0):
+    """``vmap`` of ``fn`` over the leading client axis of its first
+    ``n_client`` arguments; the last ``n_shared`` are shared by every
+    client.
+
+    On the TPU the vmapped program runs inside a ``shard_map`` over the
+    step's mesh, so each device runs its own clients' Pallas kernels
+    (flash attention, forward and backward): a Mosaic kernel cannot be
+    partitioned automatically. Shared arguments enter replicated and
+    outputs leave client-sharded. Elsewhere it is the plain vmap, which
+    the partitioner splits along 'client'."""
+    vf = jax.vmap(fn, in_axes=(0,) * n_client + (None,) * n_shared)
+    if jax.default_backend() != "tpu":
+        return vf
+    from jax.sharding import PartitionSpec as P
+
+    specs = (P("client"),) * n_client + (P(),) * n_shared
+    return jax.shard_map(vf, mesh=mesh, in_specs=specs,
+                         out_specs=P("client"), check_vma=False)
+
+
 def make_ifl_round_step(
     cfg: ModelConfig,
     mesh: Mesh,
@@ -242,7 +263,8 @@ def make_ifl_round_step(
                 )
                 return loss, g
 
-            losses, grads = jax.vmap(one_client)(bp, mod_p, mb)
+            losses, grads = _client_local(one_client, mesh, 3)(
+                bp, mod_p, mb)
             new_bp, new_ost = jax.vmap(
                 lambda p, g, s: opt.update(p, g, s, lr_base)
             )(bp, grads, ost)
@@ -265,9 +287,9 @@ def make_ifl_round_step(
         # 'client'-axis all-gather on the encoded payload, in-program
         # decode. See SPMDFusionExchange.wire for the full semantics.
         fusion_mb = jax.tree.map(lambda a: a[:, tau], batch)  # (N, Bc, ...)
-        z, _ = jax.vmap(lambda bp_k, mb_k: base_forward(bp_k, cfg, mb_k))(
-            base_p, fusion_mb
-        )  # (N, Bc, S, d_fusion), sharded P('client','data',...)
+        z = _client_local(
+            lambda bp_k, mb_k: base_forward(bp_k, cfg, mb_k)[0], mesh, 2
+        )(base_p, fusion_mb)  # (N, Bc, S, d_fusion), sharded P('client',...)
         zg, yg, valid, new_cache, ef_state = exchange.wire(
             z, fusion_mb["tokens"], mask, cache, ef_state
         )
@@ -281,10 +303,11 @@ def make_ifl_round_step(
             else:
                 z_i, y_i, w_i = chunk  # w_i: 0.0 for stale/empty slots
 
-            def one_client(mp_k):
+            def one_client(mp_k, z_i, y_i):
                 return jax.value_and_grad(_modular_loss)(mp_k, cfg, z_i, y_i)
 
-            losses, grads = jax.vmap(one_client)(mp)
+            losses, grads = _client_local(one_client, mesh, 1, 2)(
+                mp, z_i, y_i)
             new_mp, new_ost = jax.vmap(
                 lambda p, g, s: opt.update(p, g, s, lr_modular)
             )(mp, grads, ost)

@@ -98,7 +98,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_ref, l_ref,
     ) * scale  # (1, bk)
     # Causality and the ring-buffer window arrive pre-folded into the
     # validity row (slot_pos semantics) — no index arithmetic here.
-    mask = (valid_ref[...] != 0).reshape(1, -1)
+    mask = valid_ref[...] != 0  # (1, bk)
     s = jnp.where(mask, s, NEG)
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
@@ -145,12 +145,14 @@ def flash_decode_pallas(
     kern = functools.partial(_decode_kernel, scale=scale, nk=nk)
     out = pl.pallas_call(
         kern,
+        name="flash_decode",
         grid=(BH, nk),
         in_specs=[
             pl.BlockSpec((None, 1, hd), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((None, bk, hd), lambda b, j: (b, j, 0)),
             pl.BlockSpec((None, bk, hd), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((None, bk), lambda b, j: (b, j)),
+            # (BH, 1, L) so the block's last two dims (1, bk) tile.
+            pl.BlockSpec((None, 1, bk), lambda b, j: (b, 0, j)),
         ],
         out_specs=pl.BlockSpec((None, 1, hd), lambda b, j: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, 1, hd), q.dtype),
@@ -160,7 +162,7 @@ def flash_decode_pallas(
             pltpu.VMEM((1, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(q[:, None, :], k, v, valid.astype(jnp.int32))
+    )(q[:, None, :], k, v, valid.astype(jnp.int32)[:, None, :])
     return out[:, 0]
 
 
@@ -176,6 +178,41 @@ def flash_attention_pallas(
     bk: int = 256,
     interpret: bool = False,
 ) -> jnp.ndarray:
+    """Full-sequence flash attention; differentiable.
+
+    The forward pass is the Pallas kernel. The backward pass recomputes
+    attention with the jnp oracle (``ref.flash_attention_ref``) and
+    differentiates that, so training steps can run the kernel: a
+    ``pallas_call`` has no transpose rule of its own."""
+    return _flash_attention(q, k, v, causal, window, scale, bq, bk,
+                            interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_attention(q, k, v, causal, window, scale, bq, bk, interpret):
+    return _flash_forward(q, k, v, causal, window, scale, bq, bk, interpret)
+
+
+def _flash_attention_fwd(q, k, v, causal, window, scale, bq, bk, interpret):
+    out = _flash_forward(q, k, v, causal, window, scale, bq, bk, interpret)
+    return out, (q, k, v)
+
+
+def _flash_attention_bwd(causal, window, scale, bq, bk, interpret, res, g):
+    from repro.kernels.ref import flash_attention_ref
+
+    def attend(q, k, v):
+        return flash_attention_ref(q[None], k[None], v[None], causal=causal,
+                                   window=window, scale=scale)[0]
+
+    _, vjp = jax.vjp(attend, *res)
+    return vjp(g)
+
+
+_flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
+
+
+def _flash_forward(q, k, v, causal, window, scale, bq, bk, interpret):
     BH, S, hd = q.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     bq, bk = min(bq, S), min(bk, S)
@@ -188,6 +225,7 @@ def flash_attention_pallas(
     )
     return pl.pallas_call(
         kern,
+        name="flash_attention",
         grid=(BH, nq, nk),
         in_specs=[
             pl.BlockSpec((None, bq, hd), lambda b, i, j: (b, i, 0)),

@@ -111,6 +111,7 @@ def fusion_proj_pallas(
 
     return pl.pallas_call(
         kern,
+        name="fusion_proj",
         grid=(M // bm, N // bn, nk),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
@@ -191,6 +192,7 @@ def fusion_proj_quant_pallas(
 
     return pl.pallas_call(
         kern,
+        name="fusion_proj_quant",
         grid=(M // bm, nk),
         in_specs=in_specs,
         out_specs=[
@@ -219,8 +221,8 @@ def _kernel_encode(x_ref, w_ref, *refs, act: str, nk: int, has_bias: bool,
                    ef: bool, scheme, max_ratio):
     """Matmul with any wire scheme as the flush epilogue (+ EF21).
 
-    ``refs`` layout: [b_ref]? [e_ref]? scheme-const refs..
-    payload-leaf refs.. [e'_ref]? acc scratch last — the projection
+    ``refs`` layout: [b_ref]? [e_ref]? payload-leaf refs.. [e'_ref]?
+    acc scratch last — the projection
     result is encoded (and the EF residual updated) in-register on the
     final K step, so the fp32 activation tile never leaves VMEM.
     """
@@ -231,10 +233,6 @@ def _kernel_encode(x_ref, w_ref, *refs, act: str, nk: int, has_bias: bool,
     i += int(has_bias)
     e_ref = refs[i] if ef else None
     i += int(ef)
-    consts = {
-        name: refs[i + j][...] for j, name in enumerate(scheme.consts)
-    }
-    i += len(consts)
     out_refs = refs[i:-1]
     acc_ref = refs[-1]
 
@@ -250,7 +248,7 @@ def _kernel_encode(x_ref, w_ref, *refs, act: str, nk: int, has_bias: bool,
     def _flush():
         y = _epilogue(acc_ref[...], b_ref[...] if has_bias else None, act)
         c = y + e_ref[...] if ef else y
-        payload, z_hat = scheme.encode_block(c, consts)
+        payload, z_hat = scheme.encode_block(c)
         for ref, name in zip(out_refs, scheme.leaf_names):
             ref[...] = payload[name]
         if ef:
@@ -275,9 +273,9 @@ def fusion_proj_encode_pallas(
     """Projection + wire encode (+ EF21 residual update) in one launch.
 
     The ``fusion_proj_quant_pallas`` pattern generalized over the
-    ``wire_fused`` scheme family: int4 nibble-pack, top-k select,
-    count-sketch scatter — and, with ``e`` (the carried EF residual,
-    (M, N)), the EF21 epilogue ``c = y + e``, payload = encode(c),
+    ``wire_fused`` scheme family (int8_row, int4 nibble-pack) — and,
+    with ``e`` (the carried EF residual, (M, N)), the EF21 epilogue
+    ``c = y + e``, payload = encode(c),
     ``e' = clip(c - decode(payload))`` as an extra output. Same grid as
     the quant kernel: (M/bm, K/bk) with the full N in-block, K
     zero-padded to a bk multiple. Returns the payload leaf arrays in
@@ -310,12 +308,6 @@ def fusion_proj_encode_pallas(
     if ef:
         in_specs.append(pl.BlockSpec((bm, N), lambda i, k: (i, 0)))
         args.append(e)
-    for tbl in scheme.consts.values():
-        arr = jnp.asarray(tbl)
-        in_specs.append(
-            pl.BlockSpec(arr.shape, lambda i, k, _n=arr.ndim: (0,) * _n)
-        )
-        args.append(arr)
 
     out_specs = [
         pl.BlockSpec((bm, *tail), lambda i, k, _n=len(tail): (i,) + (0,) * _n)
@@ -333,6 +325,7 @@ def fusion_proj_encode_pallas(
         functools.partial(_kernel_encode, act=act, nk=nk,
                           has_bias=has_bias, ef=ef, scheme=scheme,
                           max_ratio=max_ratio),
+        name="fusion_proj_encode",
         grid=(M // bm, nk),
         in_specs=in_specs,
         out_specs=out_specs,

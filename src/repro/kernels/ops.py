@@ -10,10 +10,12 @@ flattening leading dims).
 The autotuner (``autotune_wire_blocks``) does a power-of-two search
 over (bm, bk) per (device kind, d_fusion, codec, kernel kind) and
 persists the winners to an on-disk JSON cache
-(``$REPRO_WIRE_BLOCKS_CACHE`` or ~/.cache/repro_kernels/
+(``$REPRO_WIRE_BLOCKS_CACHE`` or <checkout>/.kernel_cache/
 wire_blocks.json). ``wire_blocks`` is the cheap read side every fused
 wrapper consults, falling back to the defaults when nothing was tuned —
-tuning is an optimization, never a requirement.
+tuning is an optimization, never a requirement. A candidate that fails
+to compile is skipped off the TPU only (the interpreter may refuse what
+the chip accepts); on the chip the compiler's error propagates.
 """
 
 from __future__ import annotations
@@ -38,6 +40,7 @@ from repro.kernels.fusion_proj import (
     fusion_proj_quant_pallas,
 )
 from repro.kernels.rmsnorm import rmsnorm_pallas
+from repro.runtime import CHECKOUT
 
 
 def _on_tpu() -> bool:
@@ -257,14 +260,18 @@ def fusion_proj_encode(x, w, b=None, act: str = "none", *, codec,
     if (use_kernel and (interpret or _on_tpu()) and scheme is not None
             and scheme.d == N):
         blocks = wire_blocks(codec.name, N, kind="proj_encode")
-        xp, bm, m = _pad_rows(x2, blocks.get("bm", 256))
+        xp, bm, m = _pad_rows(
+            x2, min(blocks.get("bm", 256), wire_fused.row_cap(N)))
         ep = None
         if ef:
             ep = jnp.pad(e2, ((0, xp.shape[0] - m), (0, 0)))
         outs = fusion_proj_encode_pallas(
             xp, w, b, act, scheme=scheme, e=ep,
             max_ratio=getattr(codec, "max_ratio", None),
-            bm=bm, bk=blocks.get("bk", 512), interpret=interpret,
+            bm=bm,
+            bk=min(blocks.get("bk", 512),
+                   max(128, wire_fused.MAX_W_TILE_ELEMS // N)),
+            interpret=interpret,
         )
         outs = [o[:m] for o in outs]
         payload = {
@@ -298,8 +305,7 @@ _wire_cache_mem: Optional[dict] = None
 def _wire_cache_path() -> str:
     return os.environ.get(
         "REPRO_WIRE_BLOCKS_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro_kernels",
-                     "wire_blocks.json"),
+        str(CHECKOUT / ".kernel_cache" / "wire_blocks.json"),
     )
 
 
@@ -397,6 +403,8 @@ def autotune_wire_blocks(codec, d: int, *, kind: str = "encode",
                 _timeit(fn, args) for _ in range(reps)
             )
         except Exception:
+            if _on_tpu():
+                raise
             continue
         if best is None or t < best["us"]:
             best = dict(cand, us=round(t * 1e6, 2))
@@ -431,8 +439,7 @@ _serve_cache_mem: Optional[dict] = None
 def _serve_cache_path() -> str:
     return os.environ.get(
         "REPRO_SERVE_PLAN_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro_kernels",
-                     "serve_plan.json"),
+        str(CHECKOUT / ".kernel_cache" / "serve_plan.json"),
     )
 
 
@@ -475,10 +482,7 @@ def autotune_serve_plan(plan_key: str, timer, *,
     best = None
     for edges in edge_sets:
         for h in horizons:
-            try:
-                t = timer(int(h), [int(e) for e in edges])
-            except Exception:
-                continue
+            t = timer(int(h), [int(e) for e in edges])
             if best is None or t < best["seconds"]:
                 best = {"horizon": int(h),
                         "bucket_edges": [int(e) for e in edges],

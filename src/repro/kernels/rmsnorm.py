@@ -30,6 +30,7 @@ def rmsnorm_pallas(x: jnp.ndarray, scale: jnp.ndarray, *, eps: float = 1e-6,
     assert M % br == 0, (M, br)
     return pl.pallas_call(
         functools.partial(_kernel, eps=eps),
+        name="rmsnorm",
         grid=(M // br,),
         in_specs=[
             pl.BlockSpec((br, D), lambda i: (i, 0)),

@@ -6,9 +6,8 @@ truth for ``encoded_nbytes``/ledger parity); the kernels here produce
 bitwise-identical payloads without round-tripping the fp32 (rows,
 d_fusion) fusion signal through HBM between the pointwise stages:
 
-  wire_encode      z -> payload           (int8_row / int4 nibble-pack /
-                                           top-k select / count-sketch
-                                           scatter, in-register)
+  wire_encode      z -> payload           (int8_row / int4 nibble-pack,
+                                           in-register)
   wire_encode_ef   (z, e) -> (payload, e')  the EF21 epilogue: c = z+e,
                                            inner encode, in-register
                                            decode, trust-region-clipped
@@ -23,13 +22,13 @@ Each codec is described by a ``_WireScheme``: the payload leaf layout
 per row-block plus ``encode_block`` (which also returns the in-register
 reconstruction ``z_hat`` so the EF epilogue never re-reads the payload)
 and ``decode_block``. Scheme bodies are built from the SAME shared
-helpers the jnp codecs use (``quantize_rows_sym``,
-``ef_residual_update``, ``_sketch_tables``) and the same lax ops
-(``top_k``, scatter), so in interpret mode the fused path is bitwise
+helpers the jnp codecs use (``quantize_rows_sym``, ``pack_int4``,
+``ef_residual_update``), so in interpret mode the fused path is bitwise
 equal to the oracle — a test gate, not a tolerance.
 
-Fallback rule: anything without a scheme (fp32/bf16/fp16/int8 affine)
-or outside the supported shape envelope returns None from
+Fallback rule: anything without a scheme (fp32/bf16/fp16/int8 affine,
+top-k and count-sketch, whose top_k/scatter-add have no Pallas TPU
+lowering) or outside the supported shape envelope returns None from
 ``encode_spec``/``wire_encode`` and the caller uses the jnp path.
 Unsupported is never an error.
 
@@ -65,9 +64,19 @@ __all__ = [
     "wire_encode_ef",
 ]
 
-# Full d_fusion stays in-block (row reductions need whole rows); a
-# (256, 8192) fp32 block is 8 MB of VMEM — past that, fall back to jnp.
-MAX_FUSED_D = 8192
+# Full d_fusion stays in-block (row reductions need whole rows), so a
+# block's VMEM footprint grows with rows x d. The EF encode kernel is
+# the hungriest (z, e and e' tiles plus int32 temporaries): the TPU
+# compiler accepts a 256 x 1536 fp32 tile on a v5e and runs out of VMEM
+# at 256 x 2048. Wider rows therefore get proportionally fewer rows per
+# block (``row_cap``). decode_proj's (d, bn) weight tile is refused at
+# d = 8192 even at 48 rows, so past 4096 (the widest d_fusion of any
+# registered arch) the jnp path serves.
+MAX_BLOCK_ELEMS = 256 * 1536
+MAX_FUSED_D = 4096
+# The projection+encode epilogue also holds a (bk, d) weight tile:
+# 512 x 4096 fp32 is refused, 256 x 4096 accepted.
+MAX_W_TILE_ELEMS = 256 * 4096
 
 
 def resolve_fused(fused: Optional[bool]) -> Tuple[bool, bool]:
@@ -108,18 +117,11 @@ class _WireScheme:
     def leaf_names(self) -> Tuple[str, ...]:
         return tuple(self.leaves)
 
-    @property
-    def consts(self):
-        """Trace-time constant tables the kernel needs (name -> np
-        array). Pallas kernels may not close over array constants, so
-        these ride in as extra (whole-array) inputs to every block."""
-        return {}
-
-    def encode_block(self, c: jnp.ndarray, consts=None):
+    def encode_block(self, c: jnp.ndarray):
         """(bm, d) fp32 -> (payload dict, z_hat (bm, d) fp32)."""
         raise NotImplementedError
 
-    def decode_block(self, payload, consts=None) -> jnp.ndarray:
+    def decode_block(self, payload) -> jnp.ndarray:
         """Payload blocks -> (bm, d) fp32 reconstruction (= codec.decode)."""
         raise NotImplementedError
 
@@ -137,19 +139,20 @@ class _Int8RowScheme(_WireScheme):
     def leaves(self):
         return {"q": ((self.d,), jnp.int8), "scale": ((1,), jnp.float32)}
 
-    def encode_block(self, c, consts=None):
+    def encode_block(self, c):
         q, scale = quantize_rows_sym(c)
         return {"q": q, "scale": scale}, q.astype(jnp.float32) * scale
 
-    def decode_block(self, payload, consts=None):
+    def decode_block(self, payload):
         return payload["q"].astype(jnp.float32) * payload["scale"]
 
 
 class _Int4RowScheme(_WireScheme):
-    """Nibble-pack in-register: two int4 values per stored byte.
+    """Nibble-pack in-register: two int4 values per stored byte, in the
+    codec's split-half layout (``codec.pack_int4``).
 
     The kernel always sees an even ``d`` (an odd d_fusion is padded
-    with one zero column by the wrapper — the same zero nibble the jnp
+    with one zero column by the wrapper — the same zero column the jnp
     codec pads with, and a zero column changes no row absmax), so the
     packed width is exactly the codec's ceil(d/2) bytes per row.
     """
@@ -161,104 +164,28 @@ class _Int4RowScheme(_WireScheme):
         return {"q4": ((self.d // 2,), jnp.uint8),
                 "scale": ((1,), jnp.float32)}
 
-    def encode_block(self, c, consts=None):
+    def encode_block(self, c):
         q, scale = quantize_rows_sym(c, qmax=7)
-        u = (q + 8).astype(jnp.uint8)  # [-7,7] -> [1,15]; pad col -> 8
-        u2 = u.reshape(u.shape[0], -1, 2)
-        packed = u2[..., 0] | (u2[..., 1] << 4)
         # q is exactly what unpacking recovers, so q*scale IS the
         # codec's decode — no unpack round-trip needed for z_hat.
-        return ({"q4": packed, "scale": scale},
+        return ({"q4": codec_mod.pack_int4(q), "scale": scale},
                 q.astype(jnp.float32) * scale)
 
-    def decode_block(self, payload, consts=None):
-        packed, scale = payload["q4"], payload["scale"]
-        lo = (packed & jnp.uint8(0xF)).astype(jnp.int32) - 8
-        hi = (packed >> 4).astype(jnp.int32) - 8
-        q = jnp.stack([lo, hi], axis=-1).reshape(
-            packed.shape[0], packed.shape[-1] * 2
-        )
-        return q.astype(jnp.float32) * scale
-
-
-class _TopKScheme(_WireScheme):
-    """Per-row magnitude top-k select: values + int32 index sidecar.
-
-    Uses the same ``lax.top_k`` as the codec (stable lowest-index
-    tie-break), so the index sidecar matches the oracle bitwise.
-    """
-
-    name = "topk"
-
-    def __init__(self, d: int, k: int):
-        super().__init__(d)
-        self.k = k
-
-    @property
-    def leaves(self):
-        return {"values": ((self.k,), jnp.float32),
-                "indices": ((self.k,), jnp.int32)}
-
-    def encode_block(self, c, consts=None):
-        _, idx = jax.lax.top_k(jnp.abs(c), self.k)
-        vals = jnp.take_along_axis(c, idx, axis=-1)
-        payload = {"values": vals, "indices": idx.astype(jnp.int32)}
-        return payload, self.decode_block(payload)
-
-    def decode_block(self, payload, consts=None):
-        vals, idx = payload["values"], payload["indices"]
-        rows = vals.shape[0]
-        flat = jnp.zeros((rows, self.d), jnp.float32)
-        r = jnp.arange(rows)[:, None]
-        return flat.at[r, idx].set(vals)
-
-
-class _SketchScheme(_WireScheme):
-    """Count-sketch scatter-add into w signed buckets, in-register.
-
-    The hash/sign/inverse-count tables are the codec's own
-    ``_sketch_tables`` numpy arrays, passed to the kernel as extra
-    inputs (pallas kernels may not close over array constants) —
-    encoder, decoder, and kernel share one seed and zero wire sidecar.
-    """
-
-    name = "sketch"
-
-    def __init__(self, d: int, w: int, seed: int):
-        super().__init__(d)
-        self.w = w
-        self.h, self.s, self.inv_counts = codec_mod._sketch_tables(
-            d, w, seed
-        )
-
-    @property
-    def leaves(self):
-        return {"sketch": ((self.w,), jnp.float32)}
-
-    @property
-    def consts(self):
-        return {"h": self.h, "s": self.s, "inv_counts": self.inv_counts}
-
-    def encode_block(self, c, consts=None):
-        h, s = consts["h"], consts["s"]
-        flat = c * s
-        sk = jnp.zeros((c.shape[0], self.w), jnp.float32)
-        sk = sk.at[:, h].add(flat)
-        payload = {"sketch": sk}
-        return payload, self.decode_block(payload, consts)
-
-    def decode_block(self, payload, consts=None):
-        h, s = consts["h"], consts["s"]
-        vals = payload["sketch"] * consts["inv_counts"]  # bucket means
-        return vals[..., h] * s
+    def decode_block(self, payload):
+        q = codec_mod.unpack_int4(payload["q4"])
+        return q.astype(jnp.float32) * payload["scale"]
 
 
 def scheme_for(codec, d: int) -> Optional[_WireScheme]:
     """The wire scheme for ``codec`` at last-dim ``d``, or None.
 
-    EF is not a scheme — it is an epilogue around its inner scheme
-    (``wire_encode_ef``); its stateless encode delegates to the inner
-    codec upstream (``EFCodec.fused_encode``).
+    Only the symmetric row schemes (int8_row, int4) have one. Top-k and
+    count-sketch decline on every backend: a Pallas TPU kernel has no
+    lowering for ``top_k`` or a scatter-add, so their jnp codec is the
+    declared path, not a fallback. EF is not a scheme — it is an
+    epilogue around its inner scheme (``wire_encode_ef``); its stateless
+    encode delegates to the inner codec upstream
+    (``EFCodec.fused_encode``).
     """
     if d < 1 or d > MAX_FUSED_D:
         return None
@@ -266,10 +193,6 @@ def scheme_for(codec, d: int) -> Optional[_WireScheme]:
         return _Int8RowScheme(d)
     if isinstance(codec, codec_mod.Int4RowCodec):
         return _Int4RowScheme(d + d % 2)
-    if isinstance(codec, codec_mod.TopKCodec):
-        return _TopKScheme(d, codec.k_of(d))
-    if isinstance(codec, codec_mod.CountSketchCodec):
-        return _SketchScheme(d, codec.w_of(d), codec.seed)
     return None
 
 
@@ -285,10 +208,8 @@ def _encode_kernel(z_ref, *refs, scheme: _WireScheme, ef: bool,
         i += 1
     else:
         c = zf
-    const_names = tuple(scheme.consts)
-    consts = {name: refs[i + j][...] for j, name in enumerate(const_names)}
-    outs = refs[i + len(const_names):]
-    payload, z_hat = scheme.encode_block(c, consts)
+    outs = refs[i:]
+    payload, z_hat = scheme.encode_block(c)
     for ref, name in zip(outs, scheme.leaf_names):
         ref[...] = payload[name]
     if ef:
@@ -297,27 +218,35 @@ def _encode_kernel(z_ref, *refs, scheme: _WireScheme, ef: bool,
         )
 
 
-def _round_rows(rows: int, block_rows: Optional[int]) -> int:
+def row_cap(d: int) -> int:
+    """Most rows per block for rows of width ``d`` (a sublane multiple)
+    so that rows x d fits MAX_BLOCK_ELEMS."""
+    return max(8, MAX_BLOCK_ELEMS // d // 8 * 8)
+
+
+def _round_rows(rows: int, block_rows: Optional[int], d: int) -> int:
+    """Rows per block: the caller's (tuned) choice or 256, rounded to
+    the sublane multiple and capped by ``row_cap``."""
+    cap = row_cap(d)
     if block_rows:
-        return max(8, min(int(block_rows), 1024))
+        return max(8, min(int(block_rows), 1024, cap))
     if rows >= 256:
-        return 256
-    return -(-rows // 8) * 8  # round up to the sublane multiple
+        return min(256, cap)
+    return min(-(-rows // 8) * 8, cap)  # round up to the sublane multiple
 
 
 def _encode_call(z2, scheme: _WireScheme, *, e2=None,
                  max_ratio: Optional[float] = None,
                  block_rows: Optional[int] = None, interpret: bool = False):
     """Run the single-launch encode on a 2-D (rows, d) view."""
-    rows = z2.shape[0]
-    bm = _round_rows(rows, block_rows)
+    rows, d = z2.shape
+    bm = _round_rows(rows, block_rows, d)
     pad = -rows % bm
     if pad:
         z2 = jnp.pad(z2, ((0, pad), (0, 0)))
         if e2 is not None:
             e2 = jnp.pad(e2, ((0, pad), (0, 0)))
     m = z2.shape[0]
-    d = z2.shape[1]
     ef = e2 is not None
 
     row_spec = pl.BlockSpec((bm, d), lambda i: (i, 0))
@@ -326,12 +255,6 @@ def _encode_call(z2, scheme: _WireScheme, *, e2=None,
     if ef:
         in_specs.append(row_spec)
         args.append(e2)
-    for tbl in scheme.consts.values():
-        arr = jnp.asarray(tbl)
-        in_specs.append(
-            pl.BlockSpec(arr.shape, lambda i, _n=arr.ndim: (0,) * _n)
-        )
-        args.append(arr)
     out_specs = [
         pl.BlockSpec((bm, *tail), lambda i, _n=len(tail): (i,) + (0,) * _n)
         for tail, _ in scheme.leaves.values()
@@ -347,6 +270,7 @@ def _encode_call(z2, scheme: _WireScheme, *, e2=None,
     outs = pl.pallas_call(
         functools.partial(_encode_kernel, scheme=scheme, ef=ef,
                           max_ratio=max_ratio),
+        name="wire_encode_ef" if ef else "wire_encode",
         grid=(m // bm,),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -421,15 +345,10 @@ def _decode_proj_kernel(*refs, scheme: _WireScheme, act: str,
     payload = {
         name: refs[i][...] for i, name in enumerate(scheme.leaf_names)
     }
-    consts = {
-        name: refs[n_leaves + j][...]
-        for j, name in enumerate(scheme.consts)
-    }
-    i = n_leaves + len(consts)
-    w_ref = refs[i]
-    b_ref = refs[i + 1] if has_bias else None
+    w_ref = refs[n_leaves]
+    b_ref = refs[n_leaves + 1] if has_bias else None
     o_ref = refs[-1]
-    z_hat = scheme.decode_block(payload, consts)
+    z_hat = scheme.decode_block(payload)
     y = jnp.dot(z_hat, w_ref[...], preferred_element_type=jnp.float32)
     if has_bias:
         y = y + b_ref[...].astype(jnp.float32)
@@ -448,7 +367,7 @@ def decode_proj_pallas(payload, w, b=None, act: str = "none", *, codec,
                        interpret: bool = False):
     """Decode-as-prologue: act(decode(payload) @ w + b) in one launch.
 
-    The broadcast payload is dequantized/scattered in-register inside
+    The broadcast payload is dequantized in-register inside
     the first modular-block matmul that consumes it, so the fp32
     (rows, d_fusion) reconstruction never exists in HBM. ``payload``
     leaves must be 2-D (rows, tail) views; returns (rows, N) fp32.
@@ -457,7 +376,7 @@ def decode_proj_pallas(payload, w, b=None, act: str = "none", *, codec,
     scheme = scheme_for(codec, d)
     assert scheme is not None and scheme.d == d, (codec, d)
     N = w.shape[-1]
-    bm = _round_rows(rows, block_rows)
+    bm = _round_rows(rows, block_rows, d)
     bn = min(bn, N)
     assert N % bn == 0, (N, bn)
     pad = -rows % bm
@@ -471,12 +390,6 @@ def decode_proj_pallas(payload, w, b=None, act: str = "none", *, codec,
         for tail, _ in scheme.leaves.values()
     ]
     args = list(leaves)
-    for tbl in scheme.consts.values():
-        arr = jnp.asarray(tbl)
-        in_specs.append(
-            pl.BlockSpec(arr.shape, lambda i, j, _n=arr.ndim: (0,) * _n)
-        )
-        args.append(arr)
     in_specs.append(pl.BlockSpec((d, bn), lambda i, j: (0, j)))
     args.append(w)
     has_bias = b is not None
@@ -487,6 +400,7 @@ def decode_proj_pallas(payload, w, b=None, act: str = "none", *, codec,
     out = pl.pallas_call(
         functools.partial(_decode_proj_kernel, scheme=scheme, act=act,
                           has_bias=has_bias, n_leaves=len(scheme.leaves)),
+        name="decode_proj",
         grid=(m // bm, N // bn),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
@@ -582,7 +496,7 @@ def encode_spec(codec, shape) -> Optional[dict]:
     from repro.kernels import ops  # lazy: ops imports this module
 
     blocks = ops.wire_blocks(codec.name, d)
-    bm = _round_rows(rows, blocks.get("bm"))
+    bm = _round_rows(rows, blocks.get("bm"), d)
     traffic = encode_hbm_bytes(codec, shape, ef=False) or {}
     return {
         "kernel": f"wire_encode[{codec.name}]",

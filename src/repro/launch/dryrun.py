@@ -433,8 +433,6 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool, step_kind: str,
 
     mem = compiled.memory_analysis()
     cost_raw = compiled.cost_analysis()
-    if isinstance(cost_raw, list):  # newer jax: one dict per program
-        cost_raw = cost_raw[0] if cost_raw else {}
     hlo_text = compiled.as_text()
     # Trip-count-aware accounting: XLA cost_analysis counts while (scan)
     # bodies once, which undercounts every layer stack here. See

@@ -3,6 +3,9 @@
   PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b \
       --reduced --tenants 4 --prompt-len 32 --gen 32
 
+Without ``--reduced`` the arch is served at its published widths (one
+fp32 base block per tenant: a TPU-sized run).
+
 Decoder-only archs route through ``repro.serve.ServeEngine``: one
 personalized base block per tenant + the shared modular block, per-arch
 batch lanes, admit-on-slot-free. Enc-dec archs (cross-attention needs
@@ -31,6 +34,7 @@ from repro.models.transformer import (
     lm_decode_step,
     lm_prefill,
 )
+from repro.runtime import enable_compile_cache
 
 
 def generate(params, cfg: ModelConfig, prompts: jnp.ndarray, gen: int,
@@ -84,15 +88,22 @@ def _serve_encdec(cfg: ModelConfig, args) -> None:
 
 
 def build_demo_store(cfg: ModelConfig, arch: str, n_tenants: int,
-                     seed: int = 0):
+                     seed: int = 0, *, reduced: bool):
     """A CompositionStore of ``n_tenants`` per-tenant base blocks (each
     a different init — the stand-in for per-client personalization)
-    sharing tenant 0's modular block."""
+    sharing tenant 0's modular block.
+
+    A registered ``arch`` is registered by name, ``reduced`` or at full
+    width, and must resolve to ``cfg``; any other name registers ``cfg``
+    itself."""
     from repro.serve import CompositionStore
 
     store = CompositionStore()
     if arch in ARCH_IDS:
-        name = store.add_arch(arch, reduced=True, d_fusion=cfg.d_fusion)
+        name = store.add_arch(arch, reduced=reduced, d_fusion=cfg.d_fusion)
+        if store.cfg(name) != cfg:
+            raise ValueError(f"{arch!r} (reduced={reduced}) does not "
+                             f"resolve to the config {cfg.name!r}")
     else:
         name = store.add_arch(cfg)
     key = jax.random.PRNGKey(seed)
@@ -107,7 +118,8 @@ def build_demo_store(cfg: ModelConfig, arch: str, n_tenants: int,
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="qwen1.5-0.5b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the arch's smoke-scale variant (CPU)")
     ap.add_argument("--tenants", type=int, default=4,
                     help="concurrent tenants (= demo requests)")
     ap.add_argument("--width", type=int, default=4, help="lane width")
@@ -128,6 +140,7 @@ def main():
     args = ap.parse_args()
     args.horizon = args.horizon if args.horizon == "auto" \
         else int(args.horizon)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -141,7 +154,8 @@ def main():
 
     from repro.serve import Request, ServeEngine
 
-    store = build_demo_store(cfg, args.arch, args.tenants, args.seed)
+    store = build_demo_store(cfg, args.arch, args.tenants, args.seed,
+                             reduced=args.reduced)
     engine = ServeEngine(store, width=args.width,
                          cache_len=args.prompt_len + args.gen,
                          horizon=args.horizon)

@@ -4,10 +4,10 @@ CPU-runnable end-to-end driver for IFL (and the DP baseline) on any
 assigned architecture:
 
   PYTHONPATH=src python -m repro.launch.train --arch olmo-1b --reduced \
-      --mode ifl --rounds 30 --tau 4 --batch 4 --seq 128
+      --mode ifl --rounds 30 --tau 4 --batch 4 --seq 128 --codec "ef(int4)"
 
 ``--reduced`` uses the smoke-scale family variant; full configs are for
-real hardware (exercised here only via the dry-run).
+real hardware (a TPU, or the dry-run's AOT lowering).
 """
 
 from __future__ import annotations
@@ -18,6 +18,7 @@ import os
 
 from repro.configs import ARCH_IDS, get_config
 from repro.checkpoint import save_checkpoint
+from repro.runtime import enable_compile_cache
 from repro.train.loop import train_dp_lm, train_ifl_lm
 
 
@@ -32,10 +33,14 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=3e-3)
+    ap.add_argument("--codec", default=None,
+                    help="IFL wire codec, e.g. int8_row or 'ef(int4)' "
+                         "(default fp32)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default="results/train")
     ap.add_argument("--save-ckpt", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -48,6 +53,7 @@ def main():
             cfg, rounds=args.rounds, n_clients=args.n_clients,
             tau=args.tau, batch=args.batch, seq=args.seq,
             lr_base=args.lr, lr_modular=args.lr, seed=args.seed,
+            codec=args.codec,
         )
     else:
         out = train_dp_lm(

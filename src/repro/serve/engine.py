@@ -95,6 +95,10 @@ class ServeEngine:
         tag = ",".join(f"{a}+{m}" for a, m in pairs)
         return f"{tag}|W{self.width}|L{self.cache_len}"
 
+    def lanes(self) -> Dict[Tuple[str, str], Lane]:
+        """The engine's lanes, keyed by (base_arch, modular_arch)."""
+        return dict(self._lanes)
+
     def _lane_key(self, request: Request) -> Tuple[str, str]:
         e = self.store.entry(request.tenant)
         return (e.arch, e.modular_arch)

@@ -50,6 +50,7 @@ to the sampling program — token streams are unchanged either way
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -145,14 +146,14 @@ class Lane:
                              default_bucket_edges(self.cache_len)))
         if self.bucket_edges[-1] < self.cache_len:
             self.bucket_edges.append(self.cache_len)
-        # Device state: zeros-params filler for empty slots; every cache
-        # leaf gets a uniform leading W axis ((W,) + B=1-leaf shape), so
-        # vmap(in_axes=0) hands each slot an ordinary B=1 cache.
-        self._zero_base = jax.tree.map(jnp.zeros_like, base_template)
-        self.base_stack = jax.tree.map(
-            lambda a: jnp.broadcast_to(a[None], (self.width,) + a.shape),
-            self._zero_base,
-        )
+        # Device state: zero params for empty slots; every cache leaf
+        # gets a uniform leading W axis ((W,) + B=1-leaf shape), so
+        # vmap(in_axes=0) hands each slot an ordinary B=1 cache. Only
+        # the base block's shapes are kept, not a zero copy of it: at
+        # full width every base-block copy is a GB of device memory.
+        self._base_spec = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), base_template)
+        self.base_stack = self._empty_stack()
         cache1 = init_composed_cache(base_cfg, mod_cfg, 1, self.cache_len)
         self.cache = jax.tree.map(
             lambda a: jnp.broadcast_to(a[None],
@@ -210,7 +211,9 @@ class Lane:
             vstep = jax.vmap(self._one_slot_fn(sampling),
                              in_axes=(0, None, 0, 0, 0, 0, 0, 0))
 
-            @jax.jit
+            # The carried slot state is donated: a full-width lane's
+            # cache and stop state are rewritten in place, never copied.
+            @functools.partial(jax.jit, donate_argnums=(2, 3, 4, 5, 9))
             def hstep(stack, mod, cache, tok, pos, rem, eos, temp, topk,
                       keys):
                 def body(carry, _):
@@ -229,6 +232,14 @@ class Lane:
 
             self._hstep[key] = hstep
         return self._hstep[key]
+
+    def compiled_horizon(self, S: int):
+        """The compiled S-tick decode program at this lane's current
+        state — an in-memory cache hit once the lane has launched it.
+        For inspecting what runs on the device (HLO, memory)."""
+        return self._horizon_fn(S, self.sampling).lower(
+            self.base_stack, self.modular, self.cache, self.tok, self.pos,
+            self.rem, self.eos, self.temp, self.topk, self.keys).compile()
 
     def _admit_fn(self, P: int, sampling: bool):
         """Bucketed batch admission for bucket length ``P``: a vmapped
@@ -259,7 +270,11 @@ class Lane:
             vprefill = jax.vmap(prefill_one,
                                 in_axes=(0, None, 0, 0, 0, 0, 0))
 
-            @jax.jit
+            # Donated like the horizon step: the W stacked base blocks
+            # are scattered in place — a second copy of a full-width
+            # stack would not fit beside the first on one chip.
+            @functools.partial(
+                jax.jit, donate_argnums=(0, 2, 3, 4, 5, 6, 7, 8, 9))
             def admit(stack, mod, cache, tok, pos, rem, eos, temp, topk,
                       keys, base_rows, prompts, lens, slot_idx, max_new,
                       eos_rows, temp_rows, topk_rows, key_rows):
@@ -283,6 +298,11 @@ class Lane:
             self._admit_fns[fkey] = admit
         return self._admit_fns[fkey]
 
+    def _empty_stack(self):
+        return jax.tree.map(
+            lambda a: jnp.zeros((self.width,) + a.shape, a.dtype),
+            self._base_spec)
+
     def fresh_clone(self) -> "Lane":
         """An empty lane sharing this lane's compiled horizon/admission
         programs — the oracle's fixed-batch twin."""
@@ -291,11 +311,8 @@ class Lane:
         clone.width, clone.cache_len = self.width, self.cache_len
         clone.modular = self.modular
         clone.bucket_edges = list(self.bucket_edges)
-        clone._zero_base = self._zero_base
-        clone.base_stack = jax.tree.map(
-            lambda a: jnp.broadcast_to(a[None], (self.width,) + a.shape),
-            self._zero_base,
-        )
+        clone._base_spec = self._base_spec
+        clone.base_stack = clone._empty_stack()
         cache1 = init_composed_cache(self.base_cfg, self.mod_cfg, 1,
                                      self.cache_len)
         clone.cache = jax.tree.map(
@@ -383,7 +400,9 @@ class Lane:
                     admitted_tick=tick,
                 )
                 self.slots[slot] = SlotState(req, comp)
-            trees.extend([self._zero_base] * (W - len(group)))
+            # Pad rows repeat a real row: they are computed and dropped
+            # (slot index W), and rows are independent under vmap.
+            trees.extend([trees[0]] * (W - len(group)))
             base_rows = jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
             admit = self._admit_fn(P, self.sampling)
             (self.base_stack, self.cache, self.tok, self.pos, self.rem,

@@ -14,11 +14,13 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.config import ModelConfig
-from repro.core.comm import CommLedger, ifl_round_bytes
+from repro.core.codec import get_codec
+from repro.core.comm import CommLedger
 from repro.core.ifl_spmd import (
+    init_ef_state,
     init_ifl_state,
     make_dp_train_step,
     make_ifl_round_step,
@@ -68,45 +70,91 @@ def train_ifl_lm(
     lr_base: float = 3e-3,
     lr_modular: float = 3e-3,
     seed: int = 0,
+    codec: Optional[str] = None,
     mesh: Optional[Mesh] = None,
     log_every: int = 5,
+    return_zhat: bool = False,
 ) -> Dict:
-    """IFL rounds on an LM; returns history + comm ledger."""
+    """IFL rounds on an LM; returns history + comm ledger.
+
+    ``codec`` is the wire format of the fusion exchange (default fp32;
+    see ``repro.core.codec``); a stateful ``ef(...)`` codec carries its
+    per-client residual from round to round. Params, optimizer state
+    and the residual are made client-sharded on ``mesh`` (one client per
+    device on a ('client',1,1) mesh) and donated to the round step,
+    which is compiled before the first round: the result carries the
+    compiled step (``step``), its compile time (``compile_s``) and each
+    round's wall time. ``return_zhat`` adds the last round's
+    decoded fusion payload (``z_hat``, as the receivers trained on it).
+    """
     mesh = mesh or _one_device_ifl_mesh()
-    params, opt_state = init_ifl_state(
-        jax.random.PRNGKey(seed), cfg, n_clients=n_clients
-    )
-    step_fn = jax.jit(make_ifl_round_step(
-        cfg, mesh, n_clients=n_clients, tau=tau,
-        lr_base=lr_base, lr_modular=lr_modular,
-    ))
+    wire = get_codec(codec)
+    clients = NamedSharding(mesh, P("client"))
+    params, opt_state = jax.jit(
+        lambda k: init_ifl_state(k, cfg, n_clients=n_clients),
+        out_shardings=clients,
+    )(jax.random.PRNGKey(seed))
+    args = [params, opt_state, None]
+    donate = (0, 1)
+    if wire.has_state:
+        z_shape = (n_clients, batch, seq, cfg.d_fusion)
+        args.append(jax.jit(lambda: init_ef_state(wire, z_shape),
+                            out_shardings=clients)())
+        donate = (0, 1, 3)
     stream = SyntheticLM(cfg.vocab_size, seed=seed)
     ledger = CommLedger()
-    z_bytes = batch * seq * cfg.d_fusion * 2  # bf16 fusion activations
+    # What crosses the client boundary per client per round: the
+    # encoded fusion payload plus its int32 labels.
+    entry = (wire.encoded_nbytes((batch, seq, cfg.d_fusion))
+             + batch * seq * 4)
     hist: List[Dict] = []
+    z_hat = None
     t0 = time.time()
     with mesh:
+        args[2] = _ifl_batch(stream, cfg, n_clients, tau, batch, seq, 0)
+        step_fn = jax.jit(make_ifl_round_step(
+            cfg, mesh, n_clients=n_clients, tau=tau,
+            lr_base=lr_base, lr_modular=lr_modular, codec=wire,
+            debug_return_zhat=return_zhat,
+        ), donate_argnums=donate)
+        # Compile ahead of round 0 (the rounds hit the same cache entry).
+        tc = time.perf_counter()
+        compiled = step_fn.lower(*args).compile()
+        compile_s = time.perf_counter() - tc
         for r in range(rounds):
-            b = _ifl_batch(stream, cfg, n_clients, tau, batch, seq, r)
-            params, opt_state, m = step_fn(params, opt_state, b)
-            # ledger: what crossed the client boundary this round.
-            up = n_clients * (z_bytes + batch * seq * 4)
-            ledger.uplink += up
-            ledger.downlink += n_clients * up
-            ledger.per_round.append({"up": up, "down": n_clients * up})
+            if r:
+                args[2] = _ifl_batch(stream, cfg, n_clients, tau, batch,
+                                     seq, r)
+            tr = time.perf_counter()
+            out = step_fn(*args)
+            params, opt_state, m = out[:3]
+            args[:2] = params, opt_state
+            if wire.has_state:
+                args[3] = out[3]
             rec = {
                 "round": r,
                 "base_loss": float(m["base_loss"]),
                 "mod_loss": float(m["mod_loss"]),
-                "uplink_mb": ledger.uplink_mb,
             }
+            rec["seconds"] = time.perf_counter() - tr
+            if return_zhat:
+                z_hat = m["z_hat"]
+            up = n_clients * entry
+            ledger.uplink += up
+            ledger.downlink += n_clients * up  # every entry to every client
+            ledger.per_round.append({"up": up, "down": n_clients * up})
+            rec["uplink_mb"] = ledger.uplink_mb
             hist.append(rec)
             if r % log_every == 0:
                 print(f"  round {r:4d}  base {rec['base_loss']:.4f}  "
                       f"mod {rec['mod_loss']:.4f}  "
                       f"uplink {rec['uplink_mb']:.2f} MB  "
                       f"({time.time()-t0:.0f}s)")
-    return {"history": hist, "params": params, "ledger": ledger}
+    res = {"history": hist, "params": params, "ledger": ledger,
+           "step": compiled, "compile_s": compile_s}
+    if return_zhat:
+        res["z_hat"] = z_hat
+    return res
 
 
 def train_dp_lm(cfg: ModelConfig, *, steps: int = 50, batch: int = 8,

@@ -349,3 +349,33 @@ def test_dp_step_matches_manual_sgd():
     manual = jax.tree.map(lambda p, g: p - 0.1 * g, params, grads)
     for a, b in zip(jax.tree.leaves(new_params), jax.tree.leaves(manual)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
+
+
+def test_train_ifl_lm_carries_ef_int4_and_ledgers_its_bytes():
+    """The LM training loop at the chip smoke's shape, cut to the CPU
+    (reduced qwen1.5-0.5b, 2 clients, tau 1, one sequence each): the
+    ef(int4) residual rides from round to round, losses stay finite, the
+    decoded payload sits on each row's int4 grid, and the ledger counts
+    the codec's encoded bytes plus int32 labels per client per round."""
+    from repro.configs import get_config
+    from repro.core.codec import get_codec
+    from repro.train.loop import train_ifl_lm
+
+    cfg = get_config("qwen1.5-0.5b").reduced()
+    rounds, n, seq = 3, 2, 64
+    out = train_ifl_lm(cfg, rounds=rounds, n_clients=n, tau=1, batch=1,
+                       seq=seq, codec="ef(int4)", log_every=rounds,
+                       return_zhat=True)
+    losses = [(h["base_loss"], h["mod_loss"]) for h in out["history"]]
+    assert np.all(np.isfinite(losses)) and len(losses) == rounds
+    entry = get_codec("int4").encoded_nbytes((1, seq, cfg.d_fusion))
+    assert out["ledger"].uplink == rounds * n * (entry + seq * 4)
+    z_hat = np.asarray(out["z_hat"])
+    assert z_hat.shape == (n, 1, seq, cfg.d_fusion)
+    levels = z_hat / (np.abs(z_hat).max(axis=-1, keepdims=True) / 7.0)
+    np.testing.assert_allclose(levels, np.round(levels), atol=1e-4)
+    # The compiled round step carries the residual: it takes and
+    # returns the (n, 1, seq, d_fusion) fp32 EF state.
+    ef_args = [a for a in jax.tree.leaves(out["step"].args_info)
+               if a.shape == (n, 1, seq, cfg.d_fusion)]
+    assert ef_args and all(a.dtype == jnp.float32 for a in ef_args)
