@@ -50,6 +50,10 @@ on a derived mesh ('client', 'data', 'model'):
     each a sequential SGD step on the modular block — the pseudocode's
     per-i update order, which also microbatches the N× modular compute.
 
+Each phase's operations carry a ``jax.named_scope`` in their op
+metadata (``ifl.base``, ``ifl.exchange``, ``ifl.modular``), so a device
+trace tells the phases apart; the compiled arithmetic is unchanged.
+
 The wire pipeline of phase 2 (encode/EF/cache/all-gather/decode) is the
 exchange plane's SPMD backend
 (``repro.core.exchange.SPMDFusionExchange.wire``); this module composes
@@ -247,100 +251,106 @@ def make_ifl_round_step(
             return (losses * maskf).sum() / n_part
 
         # ---------------- Phase 1: τ local base-block updates (eq. 7).
-        def tau_batch(i_slice):
-            return jax.tree.map(lambda a: a[:, i_slice], batch)
+        with jax.named_scope("ifl.base"):
+            def tau_batch(i_slice):
+                return jax.tree.map(lambda a: a[:, i_slice], batch)
 
-        base_batches = jax.tree.map(
-            lambda a: jnp.moveaxis(a[:, :tau], 1, 0), batch
-        )  # (tau, N, Bc, ...)
+            base_batches = jax.tree.map(
+                lambda a: jnp.moveaxis(a[:, :tau], 1, 0), batch
+            )  # (tau, N, Bc, ...)
 
-        def base_step(carry, mb):
-            bp, ost = carry
+            def base_step(carry, mb):
+                bp, ost = carry
 
-            def one_client(bp_k, mod_k, mb_k):
-                loss, g = jax.value_and_grad(_full_loss_wrt_base)(
-                    bp_k, mod_k, cfg, mb_k
-                )
-                return loss, g
+                def one_client(bp_k, mod_k, mb_k):
+                    loss, g = jax.value_and_grad(_full_loss_wrt_base)(
+                        bp_k, mod_k, cfg, mb_k
+                    )
+                    return loss, g
 
-            losses, grads = _client_local(one_client, mesh, 3)(
-                bp, mod_p, mb)
-            new_bp, new_ost = jax.vmap(
-                lambda p, g, s: opt.update(p, g, s, lr_base)
-            )(bp, grads, ost)
-            return (new_bp, new_ost), client_mean(losses)
+                losses, grads = _client_local(one_client, mesh, 3)(
+                    bp, mod_p, mb)
+                new_bp, new_ost = jax.vmap(
+                    lambda p, g, s: opt.update(p, g, s, lr_base)
+                )(bp, grads, ost)
+                return (new_bp, new_ost), client_mean(losses)
 
-        (base_new, ost_b), base_losses = jax.lax.scan(
-            base_step, (base_p, opt_state["base"]), base_batches
-        )
-        if mask is None:
-            base_p = base_new
-        else:
-            # Absent clients' base params and optimizer state stay
-            # bitwise frozen (they are offline, not just unsampled).
-            base_p = _tree_where(mask, base_new, params["base"])
-            ost_b = _tree_where(mask, ost_b, opt_state["base"])
+            (base_new, ost_b), base_losses = jax.lax.scan(
+                base_step, (base_p, opt_state["base"]), base_batches
+            )
+            if mask is None:
+                base_p = base_new
+            else:
+                # Absent clients' base params and optimizer state stay
+                # bitwise frozen (they are offline, not just unsampled).
+                base_p = _tree_where(mask, base_new, params["base"])
+                ost_b = _tree_where(mask, ost_b, opt_state["base"])
 
         # ---------------- Phase 2: fusion exchange (lines 13-21) — the
         # exchange plane's jit-traceable wire block: EF-threaded masked
         # encode, carried-cache refresh with the staleness weights, THE
         # 'client'-axis all-gather on the encoded payload, in-program
         # decode. See SPMDFusionExchange.wire for the full semantics.
-        fusion_mb = jax.tree.map(lambda a: a[:, tau], batch)  # (N, Bc, ...)
-        z = _client_local(
-            lambda bp_k, mb_k: base_forward(bp_k, cfg, mb_k)[0], mesh, 2
-        )(base_p, fusion_mb)  # (N, Bc, S, d_fusion), sharded P('client',...)
-        zg, yg, valid, new_cache, ef_state = exchange.wire(
-            z, fusion_mb["tokens"], mask, cache, ef_state
-        )
+        with jax.named_scope("ifl.exchange"):
+            # (N, Bc, ...)
+            fusion_mb = jax.tree.map(lambda a: a[:, tau], batch)
+            # (N, Bc, S, d_fusion), sharded P('client', ...)
+            z = _client_local(
+                lambda bp_k, mb_k: base_forward(bp_k, cfg, mb_k)[0], mesh, 2
+            )(base_p, fusion_mb)
+            zg, yg, valid, new_cache, ef_state = exchange.wire(
+                z, fusion_mb["tokens"], mask, cache, ef_state
+            )
 
         # ---------------- Phase 3: modular updates (lines 22-31).
-        def mod_step(carry, chunk):
-            mp, ost = carry
-            if valid is None:
-                z_i, y_i = chunk  # (Bc, S, dF) replicated over 'client'
-                w_i = 1.0
+        with jax.named_scope("ifl.modular"):
+            def mod_step(carry, chunk):
+                mp, ost = carry
+                if valid is None:
+                    z_i, y_i = chunk  # (Bc, S, dF) replicated over 'client'
+                    w_i = 1.0
+                else:
+                    z_i, y_i, w_i = chunk  # w_i: 0.0 for stale/empty slots
+
+                def one_client(mp_k, z_i, y_i):
+                    return jax.value_and_grad(_modular_loss)(
+                        mp_k, cfg, z_i, y_i)
+
+                losses, grads = _client_local(one_client, mesh, 1, 2)(
+                    mp, z_i, y_i)
+                new_mp, new_ost = jax.vmap(
+                    lambda p, g, s: opt.update(p, g, s, lr_modular)
+                )(mp, grads, ost)
+                if valid is not None:
+                    # A stale/never-filled chunk must be a true no-op — the
+                    # fixed-shape analogue of the eager cache's eviction.
+                    # Select, don't zero the grads: a zero-grad update is
+                    # NOT identity for stateful optimizers (adamw's
+                    # bias-corrected momentum still moves params).
+                    new_mp = jax.tree.map(
+                        lambda n, o: jnp.where(w_i > 0, n, o), new_mp, mp)
+                    new_ost = jax.tree.map(
+                        lambda n, o: jnp.where(w_i > 0, n, o), new_ost, ost)
+                return (new_mp, new_ost), w_i * client_mean(losses)
+
+            chunks = (zg, yg) if valid is None else (zg, yg, valid)
+            (mod_new, ost_m), mod_losses = jax.lax.scan(
+                mod_step, (params["modular"], opt_state["modular"]), chunks
+            )
+            base_loss = jnp.mean(base_losses)
+            if mask is None:
+                mod_p = mod_new
+                mod_loss = jnp.mean(mod_losses)
             else:
-                z_i, y_i, w_i = chunk  # w_i: 0.0 for stale/empty slots
-
-            def one_client(mp_k, z_i, y_i):
-                return jax.value_and_grad(_modular_loss)(mp_k, cfg, z_i, y_i)
-
-            losses, grads = _client_local(one_client, mesh, 1, 2)(
-                mp, z_i, y_i)
-            new_mp, new_ost = jax.vmap(
-                lambda p, g, s: opt.update(p, g, s, lr_modular)
-            )(mp, grads, ost)
-            if valid is not None:
-                # A stale/never-filled chunk must be a true no-op — the
-                # fixed-shape analogue of the eager cache's eviction.
-                # Select, don't zero the grads: a zero-grad update is
-                # NOT identity for stateful optimizers (adamw's
-                # bias-corrected momentum still moves params).
-                new_mp = jax.tree.map(
-                    lambda n, o: jnp.where(w_i > 0, n, o), new_mp, mp)
-                new_ost = jax.tree.map(
-                    lambda n, o: jnp.where(w_i > 0, n, o), new_ost, ost)
-            return (new_mp, new_ost), w_i * client_mean(losses)
-
-        chunks = (zg, yg) if valid is None else (zg, yg, valid)
-        (mod_new, ost_m), mod_losses = jax.lax.scan(
-            mod_step, (params["modular"], opt_state["modular"]), chunks
-        )
-        base_loss = jnp.mean(base_losses)
-        if mask is None:
-            mod_p = mod_new
-            mod_loss = jnp.mean(mod_losses)
-        else:
-            mod_p = _tree_where(mask, mod_new, params["modular"])
-            ost_m = _tree_where(mask, ost_m, opt_state["modular"])
-            mod_loss = mod_losses.sum() / jnp.maximum(valid.sum(), 1.0)
-            # Empty rounds (nobody up / nothing valid) report NaN, the
-            # eager trainers' convention — not a spurious 0.0 loss.
-            empty = maskf.sum() == 0
-            base_loss = jnp.where(empty, jnp.nan, base_loss)
-            mod_loss = jnp.where(
-                empty | (valid.sum() == 0), jnp.nan, mod_loss)
+                mod_p = _tree_where(mask, mod_new, params["modular"])
+                ost_m = _tree_where(mask, ost_m, opt_state["modular"])
+                mod_loss = mod_losses.sum() / jnp.maximum(valid.sum(), 1.0)
+                # Empty rounds (nobody up / nothing valid) report NaN, the
+                # eager trainers' convention — not a spurious 0.0 loss.
+                empty = maskf.sum() == 0
+                base_loss = jnp.where(empty, jnp.nan, base_loss)
+                mod_loss = jnp.where(
+                    empty | (valid.sum() == 0), jnp.nan, mod_loss)
 
         new_params = {"base": base_p, "modular": mod_p}
         new_opt = {"base": ost_b, "modular": ost_m}

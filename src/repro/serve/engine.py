@@ -26,6 +26,15 @@ admissions, and per-token stamps are all measured in ticks, making
 staggered traffic deterministic (and the benchmark's wall-clock
 attribution exact — time the steps, map tokens to steps).
 
+Where the host's time goes: each step opens ``jax.profiler``
+annotations ``serve.step`` and, inside it, ``serve.launch``,
+``serve.fetch``, ``serve.absorb`` and ``serve.admit`` (whose bucket
+launches open ``serve.admit.stack`` and ``serve.admit.launch``). They
+cost nothing without a profiler session. ``ServeEngine.counters``
+(``EngineCounters``) counts the work launched: slot-ticks and live
+tokens of decode, positions and prompt tokens of admission, and each
+request's host-clock wait from submission to its admission launch.
+
 Correctness contract: ``oracle(request)`` replays the request alone in
 an otherwise-empty lane of the SAME width with the SAME compiled
 horizon/admission programs — by the lane's row-independence, a
@@ -35,14 +44,16 @@ continuously-batched served output is bitwise equal to its oracle.
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from repro.serve.lanes import Lane
 from repro.serve.store import CompositionStore
-from repro.serve.types import Completion, Request
+from repro.serve.types import Completion, EngineCounters, Request
 
 __all__ = ["ServeEngine"]
 
@@ -77,12 +88,14 @@ class ServeEngine:
         if self.horizon < 1:
             raise ValueError(f"horizon must be >= 1, got {horizon}")
         self._lanes: Dict[Tuple[str, str], Lane] = {}
-        # Pending queues carry (request, base params) so admission does
-        # not repeat the store.entry() lookup submit already paid.
-        self._pending: Dict[Tuple[str, str], Deque[Tuple[Request, Any]]] \
-            = {}
+        # Pending queues carry (request, base params, submission time)
+        # so admission does not repeat the store.entry() lookup submit
+        # already paid.
+        self._pending: Dict[Tuple[str, str],
+                            Deque[Tuple[Request, Any, float]]] = {}
         self._tick = 0
         self._inflight = 0
+        self.counters = EngineCounters()
 
     # ---------------------------------------------------------- lanes
 
@@ -115,7 +128,7 @@ class ServeEngine:
                 self.store.cfg(arch), self.store.cfg(mod_arch),
                 self.store.modular(mod_arch), some_tenant.base,
                 width=self.width, cache_len=self.cache_len,
-                bucket_edges=self.bucket_edges,
+                bucket_edges=self.bucket_edges, counters=self.counters,
             )
         return self._lanes[key]
 
@@ -137,7 +150,7 @@ class ServeEngine:
             )
         key = (e.arch, e.modular_arch)
         q = self._pending.setdefault(key, deque())
-        q.append((request, e.base))
+        q.append((request, e.base, time.perf_counter()))
         # FIFO by (arrival, submission order): keep the deque sorted —
         # admission must not let a late-arriving request jump the queue.
         if len(q) > 1 and request.arrival < q[-2][0].arrival:
@@ -165,31 +178,37 @@ class ServeEngine:
         admission outputs in ONE ``jax.device_get``, evict finished
         requests, then admit waiting arrivals at the boundary tick.
         Returns the completions finished this step."""
-        now, S = self._tick, self.horizon
-        for lane in self._lanes.values():
-            if lane.n_active > 0:
-                lane.launch_horizon(S, now)
-        # The single host sync of the step — every lane's (S, W) token
-        # window and every pending admission's (first, done) arrays come
-        # back in one coalesced transfer.
-        payload = {k: lane.pending_transfer()
-                   for k, lane in self._lanes.items()}
-        host = jax.device_get(payload)
-        done: List[Completion] = []
-        for k, lane in self._lanes.items():
-            done.extend(lane.absorb(host[k]))
-        # Boundary admission: bucketed batch prefill of everything
-        # admissible into the slots now free, one launch per bucket.
-        boundary = now + S - 1
-        for key, q in self._pending.items():
-            lane = self._lane(key)
-            free = len(lane.free_slots())
-            admits: List[Tuple[Request, Any]] = []
-            while q and q[0][0].arrival <= boundary and len(admits) < free:
-                admits.append(q.popleft())
-            lane.admit_batch(admits, boundary)
-        self._inflight -= len(done)
-        self._tick += S
+        with TraceAnnotation("serve.step"):
+            now, S = self._tick, self.horizon
+            with TraceAnnotation("serve.launch"):
+                for lane in self._lanes.values():
+                    if lane.n_active > 0:
+                        lane.launch_horizon(S, now)
+            # The single host sync of the step — every lane's (S, W) token
+            # window and every pending admission's (first, done) arrays come
+            # back in one coalesced transfer.
+            payload = {k: lane.pending_transfer()
+                       for k, lane in self._lanes.items()}
+            with TraceAnnotation("serve.fetch"):
+                host = jax.device_get(payload)
+            done: List[Completion] = []
+            with TraceAnnotation("serve.absorb"):
+                for k, lane in self._lanes.items():
+                    done.extend(lane.absorb(host[k]))
+            # Boundary admission: bucketed batch prefill of everything
+            # admissible into the slots now free, one launch per bucket.
+            boundary = now + S - 1
+            with TraceAnnotation("serve.admit"):
+                for key, q in self._pending.items():
+                    lane = self._lane(key)
+                    free = len(lane.free_slots())
+                    admits: List[Tuple[Request, Any, float]] = []
+                    while (q and q[0][0].arrival <= boundary
+                           and len(admits) < free):
+                        admits.append(q.popleft())
+                    lane.admit_batch(admits, boundary)
+            self._inflight -= len(done)
+            self._tick += S
         return done
 
     # ------------------------------------------------------------ run
@@ -211,7 +230,7 @@ class ServeEngine:
         per_lane: Dict[Tuple[str, str], int] = {}
         max_arr = 0
         for key, q in self._pending.items():
-            for req, _ in q:
+            for req, _, _ in q:
                 per_lane[key] = per_lane.get(key, 0) + \
                     (max(req.max_new_tokens - 1, 0) + S - 1) // S + 2
                 max_arr = max(max_arr, req.arrival)
@@ -248,12 +267,13 @@ class ServeEngine:
     def fresh_clone(self) -> "ServeEngine":
         """An empty engine over the same store whose lanes share this
         engine's compiled horizon/admission programs — the warm twin
-        the benchmark times after a throwaway compile run."""
+        the benchmark times after a throwaway compile run. Its counters
+        start at zero."""
         clone = ServeEngine(self.store, width=self.width,
                             cache_len=self.cache_len,
                             horizon=self.horizon,
                             bucket_edges=self.bucket_edges)
-        clone._lanes = {k: lane.fresh_clone()
+        clone._lanes = {k: lane.fresh_clone(counters=clone.counters)
                         for k, lane in self._lanes.items()}
         return clone
 
@@ -269,7 +289,7 @@ class ServeEngine:
         base = self.store.entry(request.tenant).base
         req0 = dataclasses.replace(request, arrival=0)
         S = self.horizon
-        lane.admit_batch([(req0, base)], S - 1)
+        lane.admit_batch([(req0, base, None)], S - 1)
         t0 = S
         budget = (max(request.max_new_tokens - 1, 0) + S - 1) // S + 3
         for _ in range(budget):

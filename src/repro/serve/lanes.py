@@ -51,11 +51,13 @@ to the sampling program — token streams are unchanged either way
 from __future__ import annotations
 
 import functools
+import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.config import ModelConfig
 from repro.models.transformer import (
@@ -63,7 +65,7 @@ from repro.models.transformer import (
     composed_prefill_ragged,
     init_composed_cache,
 )
-from repro.serve.types import Completion, Request
+from repro.serve.types import Completion, EngineCounters, Request
 
 __all__ = ["Lane", "SlotState", "default_bucket_edges", "sample_token"]
 
@@ -133,7 +135,8 @@ class Lane:
     def __init__(self, base_cfg: ModelConfig, mod_cfg: ModelConfig,
                  modular_params: Any, base_template: Any, *,
                  width: int, cache_len: int,
-                 bucket_edges: Optional[Sequence[int]] = None):
+                 bucket_edges: Optional[Sequence[int]] = None,
+                 counters: Optional[EngineCounters] = None):
         if base_cfg.d_fusion != mod_cfg.d_fusion:
             raise ValueError("lane arch pair disagrees on d_fusion")
         self.base_cfg = base_cfg
@@ -175,6 +178,8 @@ class Lane:
         self._window: Optional[Any] = None  # (S, W) device tokens
         self._window_span: Tuple[int, int] = (0, 0)  # (tick0, S)
         self.sampling = False  # upgraded on first non-greedy admit
+        # Work counts, shared with the engine that owns the lane.
+        self.counters = EngineCounters() if counters is None else counters
         # Compiled-program caches, shared with every fresh_clone so the
         # oracle and the benchmark's hot twin reuse warm programs.
         self._hstep: Dict[Tuple[int, bool], Any] = {}
@@ -303,9 +308,11 @@ class Lane:
             lambda a: jnp.zeros((self.width,) + a.shape, a.dtype),
             self._base_spec)
 
-    def fresh_clone(self) -> "Lane":
+    def fresh_clone(self, counters: Optional[EngineCounters] = None
+                    ) -> "Lane":
         """An empty lane sharing this lane's compiled horizon/admission
-        programs — the oracle's fixed-batch twin."""
+        programs — the oracle's fixed-batch twin. It counts into
+        ``counters`` (default: zeroed counters of its own)."""
         clone = object.__new__(Lane)
         clone.base_cfg, clone.mod_cfg = self.base_cfg, self.mod_cfg
         clone.width, clone.cache_len = self.width, self.cache_len
@@ -332,6 +339,7 @@ class Lane:
         clone._window = None
         clone._window_span = (0, 0)
         clone.sampling = self.sampling
+        clone.counters = EngineCounters() if counters is None else counters
         clone._hstep = self._hstep        # shared: stays warm
         clone._admit_fns = self._admit_fns
         return clone
@@ -353,68 +361,85 @@ class Lane:
 
     # -------------------------------------------------------- admit
 
-    def admit_batch(self, admits: List[Tuple[Request, Any]],
+    def admit_batch(self, admits: List[Tuple[Request, Any, Optional[float]]],
                     tick: int) -> None:
-        """Admit up to ``len(free_slots())`` requests at a horizon
-        boundary: group by prompt-length bucket and launch ONE vmapped
-        prefill + scatter per bucket.  No host sync — the first tokens
-        (and device-side EOS/length-1 completion flags) are fetched by
-        the engine's next coalesced transfer."""
+        """Admit up to ``len(free_slots())`` (request, base params,
+        submission time) entries at a horizon boundary: group by
+        prompt-length bucket and launch ONE vmapped prefill + scatter
+        per bucket.  No host sync — the first tokens (and device-side
+        EOS/length-1 completion flags) are fetched by the engine's next
+        coalesced transfer.  A request's wait from its submission time
+        (``time.perf_counter``; None: not counted) to its bucket's
+        launch goes to ``counters.queue_waits``."""
         if not admits:
             return
         free = self.free_slots()
         if len(admits) > len(free):
             raise RuntimeError("admit_batch() with too few free slots")
-        if any(r.temperature > 0 for r, _ in admits):
+        if any(r.temperature > 0 for r, _, _ in admits):
             self.sampling = True
         W = self.width
-        by_bucket: Dict[int, List[Tuple[Request, Any, int]]] = {}
-        for (req, base), slot in zip(admits, free):
+        by_bucket: Dict[int, List[Tuple[Request, Any, int,
+                                        Optional[float]]]] = {}
+        for (req, base, t_sub), slot in zip(admits, free):
             by_bucket.setdefault(self.bucket(len(req.prompt)), []).append(
-                (req, base, slot))
+                (req, base, slot, t_sub))
         for P, group in by_bucket.items():
-            prompts = np.zeros((W, P), np.int32)
-            lens = np.zeros((W,), np.int32)
-            slot_idx = np.full((W,), W, np.int32)  # W = dropped pad row
-            max_new = np.ones((W,), np.int32)
-            eos_rows = np.full((W,), -1, np.int32)
-            temp_rows = np.zeros((W,), np.float32)
-            topk_rows = np.zeros((W,), np.int32)
-            key_rows = np.zeros((W, 2), np.uint32)
-            rows: List[Tuple[int, int]] = []
-            trees = []
-            for r, (req, base, slot) in enumerate(group):
-                prompts[r, : len(req.prompt)] = req.prompt
-                lens[r] = len(req.prompt)
-                slot_idx[r] = slot
-                max_new[r] = req.max_new_tokens
-                eos_rows[r] = req.eos_id
-                temp_rows[r] = req.temperature
-                topk_rows[r] = req.top_k
-                key_rows[r] = request_key(req)
-                rows.append((r, slot))
-                trees.append(base)
-                comp = Completion(
-                    rid=req.rid, tenant=req.tenant,
-                    prompt_len=len(req.prompt), arrival=req.arrival,
-                    admitted_tick=tick,
-                )
-                self.slots[slot] = SlotState(req, comp)
-            # Pad rows repeat a real row: they are computed and dropped
-            # (slot index W), and rows are independent under vmap.
-            trees.extend([trees[0]] * (W - len(group)))
-            base_rows = jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
-            admit = self._admit_fn(P, self.sampling)
-            (self.base_stack, self.cache, self.tok, self.pos, self.rem,
-             self.eos, self.temp, self.topk, self.keys, first, done) = \
-                admit(self.base_stack, self.modular, self.cache, self.tok,
-                      self.pos, self.rem, self.eos, self.temp, self.topk,
-                      self.keys, base_rows, jnp.asarray(prompts),
-                      jnp.asarray(lens), jnp.asarray(slot_idx),
-                      jnp.asarray(max_new), jnp.asarray(eos_rows),
-                      jnp.asarray(temp_rows), jnp.asarray(topk_rows),
-                      jnp.asarray(key_rows))
+            with TraceAnnotation("serve.admit.stack", P=P, rows=len(group)):
+                prompts = np.zeros((W, P), np.int32)
+                lens = np.zeros((W,), np.int32)
+                slot_idx = np.full((W,), W, np.int32)  # W = dropped pad row
+                max_new = np.ones((W,), np.int32)
+                eos_rows = np.full((W,), -1, np.int32)
+                temp_rows = np.zeros((W,), np.float32)
+                topk_rows = np.zeros((W,), np.int32)
+                key_rows = np.zeros((W, 2), np.uint32)
+                rows: List[Tuple[int, int]] = []
+                trees = []
+                for r, (req, base, slot, _) in enumerate(group):
+                    prompts[r, : len(req.prompt)] = req.prompt
+                    lens[r] = len(req.prompt)
+                    slot_idx[r] = slot
+                    max_new[r] = req.max_new_tokens
+                    eos_rows[r] = req.eos_id
+                    temp_rows[r] = req.temperature
+                    topk_rows[r] = req.top_k
+                    key_rows[r] = request_key(req)
+                    rows.append((r, slot))
+                    trees.append(base)
+                    comp = Completion(
+                        rid=req.rid, tenant=req.tenant,
+                        prompt_len=len(req.prompt), arrival=req.arrival,
+                        admitted_tick=tick,
+                    )
+                    self.slots[slot] = SlotState(req, comp)
+                # Pad rows repeat a real row: they are computed and
+                # dropped (slot index W), and rows are independent
+                # under vmap.
+                trees.extend([trees[0]] * (W - len(group)))
+                base_rows = jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
+            with TraceAnnotation("serve.admit.launch", P=P):
+                now = time.perf_counter()
+                for req, _, _, t_sub in group:
+                    if t_sub is not None:
+                        self.counters.queue_waits.append(
+                            (req.rid, now - t_sub))
+                admit = self._admit_fn(P, self.sampling)
+                (self.base_stack, self.cache, self.tok, self.pos, self.rem,
+                 self.eos, self.temp, self.topk, self.keys, first, done) = \
+                    admit(self.base_stack, self.modular, self.cache,
+                          self.tok, self.pos, self.rem, self.eos, self.temp,
+                          self.topk, self.keys, base_rows,
+                          jnp.asarray(prompts), jnp.asarray(lens),
+                          jnp.asarray(slot_idx), jnp.asarray(max_new),
+                          jnp.asarray(eos_rows), jnp.asarray(temp_rows),
+                          jnp.asarray(topk_rows), jnp.asarray(key_rows))
             self._admits.append(_AdmitGroup(rows, first, done, tick))
+            c = self.counters
+            c.admit_launches += 1
+            c.admit_requests += len(group)
+            c.admit_prompt_tokens += int(lens.sum())
+            c.admit_positions += W * P
 
     # -------------------------------------------------------- decode
 
@@ -429,6 +454,8 @@ class Lane:
                          self.temp, self.topk, self.keys)
         self._window = window
         self._window_span = (tick0, S)
+        self.counters.decode_launches += 1
+        self.counters.decode_slot_ticks += self.width * S
 
     def pending_transfer(self) -> Dict[str, Any]:
         """Device arrays the engine must fetch this step: the horizon
@@ -473,6 +500,7 @@ class Lane:
                     t = int(window[step][i])
                     s.completion.tokens.append(t)
                     s.completion.token_ticks.append(tick0 + step)
+                    self.counters.decode_tokens += 1
                     s.remaining -= 1
                     if t == s.request.eos_id:
                         s.completion.finish_reason = "eos"

@@ -8,10 +8,14 @@ continuously batches it with other in-flight requests of the same pair.
 
 from __future__ import annotations
 
+from collections import deque
 from dataclasses import dataclass, field
-from typing import List, Optional, Sequence
+from typing import Deque, Dict, List, Sequence, Tuple
 
-__all__ = ["Request", "Completion"]
+__all__ = ["Request", "Completion", "EngineCounters"]
+
+# Queue waits an ``EngineCounters`` keeps before dropping the oldest.
+QUEUE_WAIT_RECORD = 4096
 
 
 @dataclass(frozen=True)
@@ -76,3 +80,43 @@ class Completion:
     finished_tick: int = -1
     # Tick stamp of every emitted token (first one = prefill tick).
     token_ticks: List[int] = field(default_factory=list)
+
+
+@dataclass
+class EngineCounters:
+    """Cumulative counts of the work an engine launched, counted where
+    it is launched (``Lane.launch_horizon``, ``Lane.admit_batch``,
+    ``Lane.absorb``). A reader takes the difference of two
+    ``snapshot``s to count a window.
+
+    Decode: every horizon computes ``width x S`` slot-ticks, of which
+    ``decode_tokens`` land as live tokens. Admission: every bucket
+    launch computes ``width x P`` positions (pad rows repeat a real
+    row), of which ``admit_prompt_tokens`` are prompt tokens.
+
+    ``queue_waits`` holds (rid, seconds) per admitted request: the host
+    clock (``time.perf_counter``) from ``ServeEngine.submit`` to the
+    launch of its admission, the newest ``QUEUE_WAIT_RECORD`` of them.
+    """
+
+    decode_launches: int = 0
+    decode_slot_ticks: int = 0
+    decode_tokens: int = 0
+    admit_launches: int = 0
+    admit_requests: int = 0
+    admit_prompt_tokens: int = 0
+    admit_positions: int = 0
+    queue_waits: Deque[Tuple[int, float]] = field(
+        default_factory=lambda: deque(maxlen=QUEUE_WAIT_RECORD),
+        repr=False, compare=False)
+
+    def snapshot(self) -> Dict[str, int]:
+        """The integer counts, as a plain dict."""
+        return {k: v for k, v in vars(self).items() if isinstance(v, int)}
+
+    def take_queue_waits(self) -> List[Tuple[int, float]]:
+        """The recorded (rid, seconds) waits, oldest first; the record
+        is emptied."""
+        out = list(self.queue_waits)
+        self.queue_waits.clear()
+        return out

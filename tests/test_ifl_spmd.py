@@ -379,3 +379,38 @@ def test_train_ifl_lm_carries_ef_int4_and_ledgers_its_bytes():
     ef_args = [a for a in jax.tree.leaves(out["step"].args_info)
                if a.shape == (n, 1, seq, cfg.d_fusion)]
     assert ef_args and all(a.dtype == jnp.float32 for a in ef_args)
+
+
+def test_round_step_phases_are_named_scopes_and_metadata_only(
+        setup, monkeypatch):
+    """Each phase's ops carry its ``jax.named_scope`` in op metadata,
+    and that is all the scopes add: with metadata stripped, the lowered
+    HLO equals the same step lowered with ``named_scope`` a no-op."""
+    import contextlib
+    import re
+
+    from repro.core.ifl_spmd import init_ef_state
+
+    cfg, mesh, params, opt_state, _, batch = setup
+    codec = "ef(int4)"
+    ef = init_ef_state(codec, (N, B, S, cfg.d_fusion))
+
+    def lowered():
+        step = jax.jit(make_ifl_round_step(
+            cfg, mesh, n_clients=N, tau=TAU, lr_base=1e-2, lr_modular=1e-2,
+            codec=codec))
+        with mesh:
+            return step.lower(params, opt_state, batch, ef)
+
+    scoped = lowered()
+    names = re.findall(r'op_name="([^"]*)"',
+                       scoped.as_text(dialect="hlo", debug_info=True))
+    for scope in ("ifl.base", "ifl.exchange", "ifl.modular"):
+        assert any(f"/{scope}/" in n for n in names), scope
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = lowered()
+    assert "ifl." not in plain.as_text(dialect="hlo", debug_info=True)
+    # Without debug info the text holds no metadata.
+    assert "metadata" not in scoped.as_text(dialect="hlo")
+    assert scoped.as_text(dialect="hlo") == plain.as_text(dialect="hlo")
