@@ -25,8 +25,10 @@ import jax.numpy as jnp
 from repro.config import LayerSpec, ModelConfig
 from repro.models import modules as nn
 from repro.models.attention import (
+    _project_qkv,
     attn_decode,
     attn_forward,
+    blocked_attention,
     cross_attn_cache,
     cross_attn_decode,
     cross_attn_forward,
@@ -111,20 +113,25 @@ def apply_layer(p, cfg: ModelConfig, spec: LayerSpec, x, positions, enc_out):
     return x, aux
 
 
+def _decode_mixer(p, cfg: ModelConfig, spec: LayerSpec, h, mix, pos,
+                  positions=None):
+    """One position through a layer's sequence mixer against its cache.
+    h: (B, 1, d). Returns (y, new mixer cache)."""
+    if spec.mixer == "attn":
+        return attn_decode(p["attn"], cfg, spec, h, mix, pos, positions)
+    if spec.mixer == "mamba":
+        return mamba_decode(p["mamba"], cfg, h, mix)
+    if spec.mixer == "mlstm":
+        return mlstm_decode(p["mlstm"], cfg, h, mix)
+    return slstm_decode(p["slstm"], cfg, h, mix)
+
+
 def decode_layer(p, cfg: ModelConfig, spec: LayerSpec, x, lcache, pos,
                  positions=None, cross_kv=None):
     aux_cache = dict(lcache)
     h = nn.apply_norm(p["norm1"], x, cfg.norm)
-    if spec.mixer == "attn":
-        y, aux_cache["mix"] = attn_decode(
-            p["attn"], cfg, spec, h, lcache["mix"], pos, positions
-        )
-    elif spec.mixer == "mamba":
-        y, aux_cache["mix"] = mamba_decode(p["mamba"], cfg, h, lcache["mix"])
-    elif spec.mixer == "mlstm":
-        y, aux_cache["mix"] = mlstm_decode(p["mlstm"], cfg, h, lcache["mix"])
-    else:
-        y, aux_cache["mix"] = slstm_decode(p["slstm"], cfg, h, lcache["mix"])
+    y, aux_cache["mix"] = _decode_mixer(p, cfg, spec, h, lcache["mix"], pos,
+                                        positions)
     x = x + y
     if spec.cross_attn:
         h = nn.apply_norm(p["norm_x"], x, cfg.norm)
@@ -546,13 +553,17 @@ def build_cross_caches(params: Params, cfg: ModelConfig, enc_out) -> Params:
     return out
 
 
+def _mrope_text_ids(cfg: ModelConfig, pos):
+    """Text continuation: all three M-RoPE axes share the running id."""
+    n_img = cfg.num_image_tokens
+    grid = max(1, int(n_img**0.5)) if n_img else 0
+    return (jnp.maximum(pos - n_img, 0) + grid).astype(jnp.int32)
+
+
 def _decode_positions(cfg: ModelConfig, pos, B: int):
     if cfg.rope_type == "mrope":
-        # Text continuation: all three M-RoPE axes share the running id.
-        n_img = cfg.num_image_tokens
-        grid = max(1, int(n_img**0.5)) if n_img else 0
-        tid = jnp.maximum(pos - n_img, 0) + grid
-        positions = jnp.broadcast_to(tid[None, None], (B, 1)).astype(jnp.int32)
+        tid = _mrope_text_ids(cfg, pos)
+        positions = jnp.broadcast_to(tid[None, None], (B, 1))
         return jnp.stack([positions] * 3)
     return None
 
@@ -696,43 +707,162 @@ def lm_prefill(params: Params, cfg: ModelConfig, cache: Params,
                             cache, tokens, cross_kvs, start)
 
 
+# =========================================================================
+# Admission prefill: one padded row, layer by layer
+# =========================================================================
+
+
+def prefill_parallel(cfg: ModelConfig, spec: LayerSpec, P: int) -> bool:
+    """Whether a layer's mixer runs all P prompt positions at once in the
+    admission prefill: causal attention, not latent (MLA), whose window
+    (if any) holds the whole bucket. Every other mixer steps its own
+    decode form over the positions."""
+    return (spec.mixer == "attn" and not cfg.use_mla
+            and (spec.window <= 0 or P <= spec.window))
+
+
+def prefill_layer_counts(base_cfg: ModelConfig, mod_cfg: ModelConfig,
+                         P: int) -> Tuple[int, int]:
+    """(layers, layers whose mixer takes the parallel form) of a
+    composed admission prefill at bucket length P."""
+    pre, bp, bg, _, _ = base_cfg._resolved_program()
+    _, _, _, mp, mg = mod_cfg._resolved_program()
+    layers = ([(base_cfg, s) for s in pre + bp * bg]
+              + [(mod_cfg, s) for s in mp * mg])
+    return len(layers), sum(prefill_parallel(c, s, P) for c, s in layers)
+
+
+def _prefill_positions(cfg: ModelConfig, P: int):
+    """Rotary ids of positions 0..P-1, as the decode step gives each."""
+    t = jnp.arange(P, dtype=jnp.int32)
+    if cfg.rope_type == "mrope":
+        return jnp.stack([_mrope_text_ids(cfg, t)[None]] * 3)
+    return t[None]
+
+
+def _prefill_attn(p, cfg: ModelConfig, spec: LayerSpec, h, mix, length):
+    """Causal self-attention over the whole row h: (1, P, d); writes K/V
+    rows [0, length) into the fresh cache at slots 0..length-1 (pad rows
+    keep the cache's zeros and slot id -1)."""
+    B, P, _ = h.shape
+    q, k, v = _project_qkv(p, cfg, spec, h, _prefill_positions(cfg, P))
+    qb = cfg.q_block if P % cfg.q_block == 0 else P
+    o = blocked_attention(q, k, v, window=spec.window, q_block=qb)
+    y = nn.linear(p["wo"], o.reshape(B, P, -1))
+    t = jnp.arange(P, dtype=jnp.int32)
+    live = t < length
+
+    def write(c, new):
+        rows = jnp.where(live[None, :, None, None], new.astype(c.dtype),
+                         c[:, :P])
+        return c.at[:, :P].set(rows)
+
+    spos = mix["slot_pos"].at[:P].set(
+        jnp.where(live, t, mix["slot_pos"][:P]))
+    return y, {"k": write(mix["k"], k), "v": write(mix["v"], v),
+               "slot_pos": spos}
+
+
+def _prefill_stepped(p, cfg: ModelConfig, spec: LayerSpec, h, mix, length):
+    """The layer's decode mixer scanned over the positions of h: (1, P,
+    d), its state frozen from ``length`` on — the state a token-serial
+    prefill leaves."""
+
+    def body(mix, inp):
+        t, ht = inp
+        y, new = _decode_mixer(p, cfg, spec, ht[:, None], mix, t,
+                               _decode_positions(cfg, t, 1))
+        mix = jax.tree.map(lambda o, n: jnp.where(t < length, n, o),
+                           mix, new)
+        return mix, y[:, 0]
+
+    P = h.shape[1]
+    mix, ys = jax.lax.scan(
+        body, mix, (jnp.arange(P, dtype=jnp.int32), h.swapaxes(0, 1)))
+    return ys.swapaxes(0, 1), mix
+
+
+def prefill_layer(p, cfg: ModelConfig, spec: LayerSpec, x, lcache, length):
+    """One layer over a padded row x: (1, P, d) whose first ``length``
+    positions are real. The FFN runs on all P rows at once; an MoE FFN
+    routes one token at a time, with decode's per-token capacity, so
+    pad tokens never compete with real ones for an expert."""
+    h = nn.apply_norm(p["norm1"], x, cfg.norm)
+    if prefill_parallel(cfg, spec, x.shape[1]):
+        y, mix = _prefill_attn(p["attn"], cfg, spec, h, lcache["mix"],
+                               length)
+    else:
+        y, mix = _prefill_stepped(p, cfg, spec, h, lcache["mix"], length)
+    x = x + y
+    if spec.ffn == "dense":
+        x = x + mlp_forward(p["ffn"], nn.apply_norm(p["norm2"], x, cfg.norm),
+                            cfg.act)
+    elif spec.ffn == "moe":
+        def one_token(ht):  # (1, d)
+            return moe_forward(p["moe"], cfg, ht[:, None])[0][:, 0]
+
+        h = nn.apply_norm(p["norm2"], x, cfg.norm)
+        x = x + jax.vmap(one_token, in_axes=1, out_axes=1)(h)
+    return x, {**lcache, "mix": mix}
+
+
+def _prefill_groups(groups_p, caches, cfg: ModelConfig, pattern, x, length):
+    """Scan the stacked groups, as ``decode_scan_groups`` does."""
+
+    def body(x, inp):
+        gp, gc = inp
+        new_gc = {}
+        for i, spec in enumerate(pattern):
+            x, new_gc[f"l{i}"] = prefill_layer(
+                gp[f"l{i}"], cfg, spec, x, gc[f"l{i}"], length)
+        return x, new_gc
+
+    return jax.lax.scan(body, x, (groups_p, caches))
+
+
 def composed_prefill_ragged(base: Params, base_cfg: ModelConfig,
                             mod: Params, mod_cfg: ModelConfig,
                             cache: Params, tokens: jnp.ndarray,
                             length: jnp.ndarray):
-    """Cached prefill of ONE row padded to a bucket length: a scan over
-    all P padded positions where steps at ``t >= length`` are frozen —
-    the computed cache/logits are discarded via ``jnp.where``, so the
-    cache (and the last live position's logits) are bitwise what an
-    unpadded ``composed_prefill`` of the first ``length`` tokens would
-    have produced.  This is what makes prompt-length *buckets* exact:
+    """Cached prefill of ONE row padded to a bucket length, layer-major:
+    the whole row goes through each layer at once (attention causally
+    over all P positions; recurrent, latent and over-long windowed
+    mixers step their decode form over the positions inside the layer),
+    and the final norm and LM head run on position ``length - 1`` only.
+
+    The cache it leaves has the layout, valid mask and contents of
+    ``length`` token-serial decode steps, up to the rounding of the
+    wider matmuls: K/V and recurrent state come from positions
+    ``[0, length)`` only, and pad positions, all after ``length``,
+    cannot reach a real one (causal attention, forward recurrences).
+    A row's result depends only on its own (params, tokens, length):
     the serving plane vmaps this over a stacked admission batch, every
-    row carrying its own true length, and a row's result depends only on
-    its own (params, tokens, length) — pad rows and pad positions
-    cannot perturb it.
+    row carrying its own true length, and pad rows cannot perturb it.
 
     tokens: (P,) int32 (positions ``0..length-1`` real, rest pad);
     length: scalar int32.  Returns (last real position's logits (V,)
     fp32, cache).  The cache must be a fresh B=1 ``init_composed_cache``
-    tree (frozen steps keep its untouched rows bitwise).
+    tree (pad rows keep its zeros and slot ids).
     """
-    P = tokens.shape[0]
-
-    def body(carry, inp):
-        cache, last = carry
-        t, tok = inp
-        logits, new_cache = composed_decode_step(
-            base, base_cfg, mod, mod_cfg, cache, tok.reshape(1, 1), t,
-        )
-        live = t < length
-        cache = jax.tree.map(lambda o, n: jnp.where(live, n, o),
-                             cache, new_cache)
-        last = jnp.where(live, logits[0, -1], last)
-        return (cache, last), None
-
-    last0 = jnp.zeros((mod_cfg.vocab_size,), jnp.float32)
-    (cache, last), _ = jax.lax.scan(
-        body, (cache, last0),
-        (jnp.arange(P, dtype=jnp.int32), tokens),
-    )
-    return last, cache
+    pre, bp, bg, _, _ = base_cfg._resolved_program()
+    _, _, _, mp, mg = mod_cfg._resolved_program()
+    cdt = nn.dtype_of(base_cfg.compute_dtype)
+    x = nn.embedding(base["embed"], tokens[None], compute_dtype=cdt)
+    new_cache: Params = {}
+    if pre:
+        new_cache["prefix"] = {}
+        for i, spec in enumerate(pre):
+            x, new_cache["prefix"][f"l{i}"] = prefill_layer(
+                base["prefix"][f"l{i}"], base_cfg, spec, x,
+                cache["prefix"][f"l{i}"], length)
+    if bg:
+        x, new_cache["base"] = _prefill_groups(
+            base["groups"], cache["base"], base_cfg, bp, x, length)
+    z = nn.linear(base["fusion_in"], x).astype(cdt)
+    x = nn.linear(mod["fusion_out"], z)
+    if mg:
+        x, new_cache["mod"] = _prefill_groups(
+            mod["groups"], cache["mod"], mod_cfg, mp, x, length)
+    x = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
+    x = nn.apply_norm(mod["final_norm"], x, mod_cfg.norm)
+    return _head_logits(mod, mod_cfg, x)[0, 0], new_cache

@@ -20,11 +20,15 @@ ONE coalesced ``jax.device_get`` per engine step.
 Admission is bucketed batch prefill: the engine hands the lane a list
 of requests at a horizon boundary, the lane groups them into padded
 prompt-length buckets and runs ONE vmapped ragged prefill + slot
-scatter per bucket (``composed_prefill_ragged`` freezes the padded
-positions, so a row's cache is bitwise its unpadded prefill's).  The
-admission batch is always W rows (pad rows scatter into slot index W —
-dropped), so the compiled program is identical however many requests
-are admitted, and identical to the oracle's single-request admission.
+scatter per bucket.  ``composed_prefill_ragged`` runs a row through
+each layer at once and the LM head on its last real position only;
+pad positions cannot reach real ones, so a row's cache holds what
+token-serial decode steps over its prompt would have written, up to
+the rounding of the wider matmuls (a bucket's length sets their
+shapes).  The admission batch is always W rows (pad rows scatter into
+slot index W — dropped), so the compiled program is identical however
+many requests are admitted, and identical to the oracle's
+single-request admission: engine and oracle stay bitwise equal.
 EOS/length-1 completion of the prefill token is checked ON DEVICE (the
 slot's remaining counter starts at 0) and the host read of the first
 token is deferred to the next boundary's coalesced transfer.
@@ -64,6 +68,7 @@ from repro.models.transformer import (
     composed_decode_step,
     composed_prefill_ragged,
     init_composed_cache,
+    prefill_layer_counts,
 )
 from repro.serve.types import Completion, EngineCounters, Request
 
@@ -440,6 +445,10 @@ class Lane:
             c.admit_requests += len(group)
             c.admit_prompt_tokens += int(lens.sum())
             c.admit_positions += W * P
+            layers, parallel = prefill_layer_counts(self.base_cfg,
+                                                    self.mod_cfg, P)
+            c.admit_layer_positions += W * P * layers
+            c.admit_parallel_layer_positions += W * P * parallel
 
     # -------------------------------------------------------- decode
 

@@ -92,7 +92,12 @@ class EngineCounters:
     Decode: every horizon computes ``width x S`` slot-ticks, of which
     ``decode_tokens`` land as live tokens. Admission: every bucket
     launch computes ``width x P`` positions (pad rows repeat a real
-    row), of which ``admit_prompt_tokens`` are prompt tokens.
+    row), of which ``admit_prompt_tokens`` are prompt tokens. Each
+    position goes through every layer: ``admit_layer_positions`` grows
+    by ``width x P x layers`` a launch, and
+    ``admit_parallel_layer_positions`` by ``width x P`` x the layers
+    whose mixer ran all P positions at once (causal attention); the
+    others step their decode form over the positions.
 
     ``queue_waits`` holds (rid, seconds) per admitted request: the host
     clock (``time.perf_counter``) from ``ServeEngine.submit`` to the
@@ -106,6 +111,8 @@ class EngineCounters:
     admit_requests: int = 0
     admit_prompt_tokens: int = 0
     admit_positions: int = 0
+    admit_layer_positions: int = 0
+    admit_parallel_layer_positions: int = 0
     queue_waits: Deque[Tuple[int, float]] = field(
         default_factory=lambda: deque(maxlen=QUEUE_WAIT_RECORD),
         repr=False, compare=False)

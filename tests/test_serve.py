@@ -126,16 +126,24 @@ def test_admission_is_fifo_by_arrival():
 
 
 def test_eos_evicts_and_frees_slot():
-    """Pick the oracle's 3rd generated token as eos: the engine must
-    stop there (tokens include the eos), finish_reason='eos', and the
-    freed slot must admit the next queued request."""
+    """Pick as eos the oracle's first token, from the 3rd on, that no
+    earlier token repeats: the engine must stop there (tokens include
+    the eos), finish_reason='eos', and the freed slot must admit the
+    next queued request."""
     store = _smoke_store()
     eng = ServeEngine(store, width=1, cache_len=32)
-    probe = Request(rid=0, tenant="t2", prompt=[9, 8, 7], max_new_tokens=8)
-    oracle_tokens = eng.oracle(probe).tokens
-    eos = oracle_tokens[2]
+    for tenant in ("t2", "t3", "t4", "t5"):
+        probe = Request(rid=0, tenant=tenant, prompt=[9, 8, 7],
+                        max_new_tokens=8)
+        oracle_tokens = eng.oracle(probe).tokens
+        fresh = [k for k in range(2, 8)
+                 if oracle_tokens[k] not in oracle_tokens[:k]]
+        if fresh:
+            break
+    k = fresh[0]
+    eos = oracle_tokens[k]
     reqs = [
-        Request(rid=0, tenant="t2", prompt=[9, 8, 7], max_new_tokens=8,
+        Request(rid=0, tenant=tenant, prompt=[9, 8, 7], max_new_tokens=8,
                 eos_id=eos),
         Request(rid=1, tenant="t3", prompt=[1, 2, 3], max_new_tokens=3,
                 arrival=0),
@@ -143,7 +151,7 @@ def test_eos_evicts_and_frees_slot():
     comps = eng.run(reqs)
     c0, c1 = comps
     assert c0.finish_reason == "eos"
-    assert c0.tokens == oracle_tokens[:3]       # eos token included
+    assert c0.tokens == oracle_tokens[:k + 1]   # eos token included
     assert c1.finish_reason == "length"
     assert len(c1.tokens) == 3
     # Width 1: rid 1 could only start after rid 0's eviction.

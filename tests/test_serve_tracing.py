@@ -10,8 +10,11 @@ import jax
 import pytest
 from jax.profiler import ProfileData
 
-from test_serve import _requests, _smoke_store
-from repro.serve import Request, ServeEngine
+from test_serve import VOCAB, _requests, _smoke_store
+from repro.api.spmd import smoke_model_config
+from repro.config import LayerSpec, ModelConfig
+from repro.models.transformer import init_lm
+from repro.serve import CompositionStore, Request, ServeEngine
 
 W, S, L = 3, 4, 32
 STORE = _smoke_store(6)
@@ -59,6 +62,44 @@ def test_fresh_clone_starts_with_zeroed_counters(donor):
     assert clone.counters is not eng.counters
     assert all(lane.counters is clone.counters
                for lane in clone.lanes().values())
+
+
+def test_attention_lane_counts_every_layer_position_parallel(donor):
+    """Every admitted position goes through every layer; in an
+    all-attention lane each layer takes the parallel form. A fresh clone
+    counts both from zero."""
+    eng = donor.fresh_clone()
+    eng.run(_requests(6, seed=6))
+    c = eng.counters
+    layers = smoke_model_config().num_layers
+    assert c.admit_layer_positions == layers * c.admit_positions > 0
+    assert c.admit_parallel_layer_positions == c.admit_layer_positions
+    clone = eng.fresh_clone().counters
+    assert clone.admit_layer_positions == 0
+    assert clone.admit_parallel_layer_positions == 0
+
+
+def test_recurrent_lane_counts_no_parallel_layer_positions():
+    cfg = ModelConfig(
+        name="vendor-xlstm", num_layers=4, d_ff=0, rope_type="none",
+        base_pattern=(LayerSpec(mixer="mlstm", ffn="none"),),
+        base_groups=2,
+        mod_pattern=(LayerSpec(mixer="slstm", ffn="none"),), mod_groups=2,
+        vocab_size=VOCAB, d_fusion=32, d_model=48, num_heads=2,
+        num_kv_heads=2, compute_dtype="float32", remat="none", q_block=16,
+        mlstm_chunk=8,
+    ).validate()
+    params = init_lm(jax.random.PRNGKey(0), cfg)
+    store = CompositionStore()
+    store.add_arch(cfg)
+    store.set_modular(cfg.name, params["modular"])
+    store.add_tenant("r", cfg.name, params["base"])
+    eng = ServeEngine(store, width=2, cache_len=16)
+    eng.run([Request(rid=i, tenant="r", prompt=[1, 2, 3 + i],
+                     max_new_tokens=2) for i in range(3)])
+    c = eng.counters
+    assert c.admit_layer_positions == cfg.num_layers * c.admit_positions > 0
+    assert c.admit_parallel_layer_positions == 0
 
 
 def _trace_files(d):
