@@ -7,6 +7,15 @@ a different tenant), ONE shared modular block (vmap ``in_axes=None`` —
 instantiated once, reused by every slot), stacked per-slot B=1 decode
 caches, and per-slot decode positions.
 
+The lane holds each param leaf in its half's compute dtype wherever the
+lane's programs read that leaf only through a cast to it
+(``held_dtypes``): the cast runs once, at lane construction for the
+modular block and in each admission's stack of the W base blocks, not
+in every launch.  The programs then see the very operands the cast
+gave them, so tokens are unchanged; leaves read otherwise (norm scales,
+Mamba's ``a_log``; everything under float32 compute) keep the store's
+dtype.  The ``CompositionStore`` keeps its own float32 masters.
+
 The hot loop is device-resident (ISSUE 10): one *horizon* launch
 advances every slot S ticks — a ``lax.scan`` of the same vmapped
 per-slot step the tick engine always ran — with per-slot stop state
@@ -39,7 +48,8 @@ of that slot's (params, cache, token, pos, key) ONLY — ``vmap`` maps
 each slot through the same per-slot program, so other slots' contents,
 admissions and evictions cannot perturb it.  An engine-served request
 is therefore bitwise equal to the same request served alone in an
-otherwise-empty width-W lane (``ServeEngine.oracle``).  Empty slots
+otherwise-empty width-W lane (``ServeEngine.oracle``), which holds
+its params in the same dtypes and runs the same programs.  Empty slots
 carry zero params + a fresh cache, which decodes to finite garbage
 (fresh attention caches are fully-invalid -> zero context) that nobody
 reads.
@@ -64,6 +74,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.config import ModelConfig
+from repro.models.modules import param_bytes
 from repro.models.transformer import (
     composed_decode_step,
     composed_prefill_ragged,
@@ -72,7 +83,8 @@ from repro.models.transformer import (
 )
 from repro.serve.types import Completion, EngineCounters, Request
 
-__all__ = ["Lane", "SlotState", "default_bucket_edges", "sample_token"]
+__all__ = ["Lane", "SlotState", "default_bucket_edges", "held_dtypes",
+           "sample_token"]
 
 
 def default_bucket_edges(cache_len: int) -> List[int]:
@@ -109,6 +121,85 @@ def sample_token(logits: jnp.ndarray, key: jnp.ndarray,
     t = jnp.where(temperature > 0, temperature, 1.0)
     drawn = jax.random.categorical(key, filt / t).astype(jnp.int32)
     return jnp.where(temperature > 0, drawn, greedy)
+
+
+# Primitives that move a leaf's elements without computing on them.
+_LAYOUT_PRIMS = frozenset({"reshape", "squeeze", "expand_dims", "transpose",
+                           "slice", "dynamic_slice", "broadcast_in_dim"})
+
+
+def _read_as(jaxpr, var, dtype) -> Optional[bool]:
+    """Whether every read of ``var`` in ``jaxpr`` ends in a cast to
+    ``dtype``, followed through layout-only primitives and into the
+    bodies of ``jit``, ``closed_call`` and ``scan`` (not its carry).
+    None where ``var`` is not read at all."""
+    if any(o is var for o in jaxpr.outvars):
+        return False
+    seen = None
+    for eqn in jaxpr.eqns:
+        for i, x in enumerate(eqn.invars):
+            if x is not var:
+                continue
+            name, prm = eqn.primitive.name, eqn.params
+            if name == "convert_element_type":
+                ok = prm["new_dtype"] == dtype
+            elif name in _LAYOUT_PRIMS and i == 0:
+                ok = _read_as(jaxpr, eqn.outvars[0], dtype)
+            elif name in ("jit", "pjit", "closed_call"):
+                sub = prm["jaxpr" if "jaxpr" in prm else "call_jaxpr"].jaxpr
+                ok = _read_as(sub, sub.invars[i], dtype)
+            elif name == "scan" and not (
+                    prm["num_consts"] <= i
+                    < prm["num_consts"] + prm["num_carry"]):
+                sub = prm["jaxpr"].jaxpr
+                ok = _read_as(sub, sub.invars[i], dtype)
+            else:
+                ok = False
+            if ok is False:
+                return False
+            seen = seen or ok
+    return seen
+
+
+def held_dtypes(base_cfg: ModelConfig, mod_cfg: ModelConfig, base: Any,
+                mod: Any, cache_len: int, bucket_edges: Sequence[int]):
+    """The dtype a lane holds each leaf of the base and the modular
+    block in: the half's compute dtype where every read of the leaf, in
+    the per-slot decode step and in the admission prefill at the
+    smallest and the largest bucket (so both forms of a windowed layer),
+    is a cast to that dtype; else the leaf's own. The programs are
+    traced abstractly (``jax.make_jaxpr``), nothing is compiled.
+    Returns (base dtypes, modular dtypes) as trees."""
+    cache = jax.eval_shape(
+        lambda: init_composed_cache(base_cfg, mod_cfg, 1, cache_len))
+    i32 = jax.ShapeDtypeStruct((), jnp.int32)
+
+    def decode(b, m, cache, tok, pos):
+        return composed_decode_step(b, base_cfg, m, mod_cfg, cache, tok, pos)
+
+    def prefill(b, m, cache, tokens, length):
+        return composed_prefill_ragged(b, base_cfg, m, mod_cfg, cache,
+                                       tokens, length)
+
+    programs = [(decode, jax.ShapeDtypeStruct((1, 1), jnp.int32))] + [
+        (prefill, jax.ShapeDtypeStruct((P,), jnp.int32))
+        for P in sorted({min(bucket_edges), max(bucket_edges)})]
+    b_leaves, b_def = jax.tree.flatten(base)
+    m_leaves, m_def = jax.tree.flatten(mod)
+    leaves = b_leaves + m_leaves
+    cdts = ([jnp.dtype(base_cfg.compute_dtype)] * len(b_leaves)
+            + [jnp.dtype(mod_cfg.compute_dtype)] * len(m_leaves))
+    reads: List[Optional[bool]] = [None] * len(leaves)
+    for fn, tokens in programs:
+        jaxpr = jax.make_jaxpr(fn)(base, mod, cache, tokens, i32).jaxpr
+        for k, (v, dt) in enumerate(zip(jaxpr.invars, cdts)):
+            if reads[k] is not False:
+                r = _read_as(jaxpr, v, dt)
+                reads[k] = r if r is False else (reads[k] or r)
+    dts = [dt if r else jnp.dtype(a.dtype)
+           for r, dt, a in zip(reads, cdts, leaves)]
+    return (b_def.unflatten(dts[:len(b_leaves)]),
+            m_def.unflatten(dts[len(b_leaves):]))
 
 
 class SlotState:
@@ -148,20 +239,36 @@ class Lane:
         self.mod_cfg = mod_cfg
         self.width = int(width)
         self.cache_len = int(cache_len)
-        self.modular = modular_params
         self.bucket_edges = sorted(
             int(e) for e in (bucket_edges or
                              default_bucket_edges(self.cache_len)))
         if self.bucket_edges[-1] < self.cache_len:
             self.bucket_edges.append(self.cache_len)
+        # Params in the dtypes the programs read them in: the modular
+        # block is cast here, once; base blocks at each admission.
+        base_dts, mod_dts = held_dtypes(base_cfg, mod_cfg, base_template,
+                                        modular_params, self.cache_len,
+                                        self.bucket_edges)
+        self.modular = jax.tree.map(lambda a, dt: a.astype(dt),
+                                    modular_params, mod_dts)
         # Device state: zero params for empty slots; every cache leaf
         # gets a uniform leading W axis ((W,) + B=1-leaf shape), so
         # vmap(in_axes=0) hands each slot an ordinary B=1 cache. Only
         # the base block's shapes are kept, not a zero copy of it: at
         # full width every base-block copy is a GB of device memory.
         self._base_spec = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), base_template)
+            lambda a, dt: jax.ShapeDtypeStruct(a.shape, dt),
+            base_template, base_dts)
         self.base_stack = self._empty_stack()
+        # Param bytes a launch reads, as stored: one base block and the
+        # part of it in the compute dtype, the same of the modular block.
+        cb, cm = (jnp.dtype(c.compute_dtype) for c in (base_cfg, mod_cfg))
+        leaves = jax.tree.leaves
+        self._param_bytes = (
+            param_bytes(self._base_spec),
+            param_bytes([a for a in leaves(self._base_spec) if a.dtype == cb]),
+            param_bytes(self.modular),
+            param_bytes([a for a in leaves(self.modular) if a.dtype == cm]))
         cache1 = init_composed_cache(base_cfg, mod_cfg, 1, self.cache_len)
         self.cache = jax.tree.map(
             lambda a: jnp.broadcast_to(a[None],
@@ -189,6 +296,7 @@ class Lane:
         # oracle and the benchmark's hot twin reuse warm programs.
         self._hstep: Dict[Tuple[int, bool], Any] = {}
         self._admit_fns: Dict[Tuple[int, bool], Any] = {}
+        self._stack_rows = self._stack_rows_fn()
 
     # ------------------------------------------------------ jitted fns
 
@@ -308,6 +416,18 @@ class Lane:
             self._admit_fns[fkey] = admit
         return self._admit_fns[fkey]
 
+    def _stack_rows_fn(self):
+        """One program for an admission's W base blocks: stacked into
+        a leading W axis and cast to the held dtypes."""
+        spec = self._base_spec
+
+        @jax.jit
+        def stack_rows(trees):
+            return jax.tree.map(
+                lambda s, *xs: jnp.stack(xs).astype(s.dtype), spec, *trees)
+
+        return stack_rows
+
     def _empty_stack(self):
         return jax.tree.map(
             lambda a: jnp.zeros((self.width,) + a.shape, a.dtype),
@@ -324,6 +444,7 @@ class Lane:
         clone.modular = self.modular
         clone.bucket_edges = list(self.bucket_edges)
         clone._base_spec = self._base_spec
+        clone._param_bytes = self._param_bytes
         clone.base_stack = clone._empty_stack()
         cache1 = init_composed_cache(self.base_cfg, self.mod_cfg, 1,
                                      self.cache_len)
@@ -347,6 +468,7 @@ class Lane:
         clone.counters = EngineCounters() if counters is None else counters
         clone._hstep = self._hstep        # shared: stays warm
         clone._admit_fns = self._admit_fns
+        clone._stack_rows = self._stack_rows
         return clone
 
     # ------------------------------------------------------- occupancy
@@ -422,7 +544,7 @@ class Lane:
                 # dropped (slot index W), and rows are independent
                 # under vmap.
                 trees.extend([trees[0]] * (W - len(group)))
-                base_rows = jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
+                base_rows = self._stack_rows(trees)
             with TraceAnnotation("serve.admit.launch", P=P):
                 now = time.perf_counter()
                 for req, _, _, t_sub in group:
@@ -445,6 +567,7 @@ class Lane:
             c.admit_requests += len(group)
             c.admit_prompt_tokens += int(lens.sum())
             c.admit_positions += W * P
+            c.admit_param_bytes += W * self._param_bytes[0]
             layers, parallel = prefill_layer_counts(self.base_cfg,
                                                     self.mod_cfg, P)
             c.admit_layer_positions += W * P * layers
@@ -463,8 +586,12 @@ class Lane:
                          self.temp, self.topk, self.keys)
         self._window = window
         self._window_span = (tick0, S)
-        self.counters.decode_launches += 1
-        self.counters.decode_slot_ticks += self.width * S
+        base, base_held, mod, mod_held = self._param_bytes
+        c = self.counters
+        c.decode_launches += 1
+        c.decode_slot_ticks += self.width * S
+        c.decode_param_bytes += self.width * base + mod
+        c.decode_param_bytes_held += self.width * base_held + mod_held
 
     def pending_transfer(self) -> Dict[str, Any]:
         """Device arrays the engine must fetch this step: the horizon

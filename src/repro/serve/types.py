@@ -99,6 +99,14 @@ class EngineCounters:
     whose mixer ran all P positions at once (causal attention); the
     others step their decode form over the positions.
 
+    Param bytes, as the lane stores them (``Lane`` holds a leaf in the
+    compute dtype where its programs only read it through a cast to
+    it): every horizon adds ``width`` base blocks and the modular block
+    to ``decode_param_bytes``, and the part of them held in the compute
+    dtype to ``decode_param_bytes_held``; every bucket launch adds the
+    ``width`` base blocks it stacks and scatters to
+    ``admit_param_bytes``.
+
     ``queue_waits`` holds (rid, seconds) per admitted request: the host
     clock (``time.perf_counter``) from ``ServeEngine.submit`` to the
     launch of its admission, the newest ``QUEUE_WAIT_RECORD`` of them.
@@ -107,12 +115,15 @@ class EngineCounters:
     decode_launches: int = 0
     decode_slot_ticks: int = 0
     decode_tokens: int = 0
+    decode_param_bytes: int = 0
+    decode_param_bytes_held: int = 0
     admit_launches: int = 0
     admit_requests: int = 0
     admit_prompt_tokens: int = 0
     admit_positions: int = 0
     admit_layer_positions: int = 0
     admit_parallel_layer_positions: int = 0
+    admit_param_bytes: int = 0
     queue_waits: Deque[Tuple[int, float]] = field(
         default_factory=lambda: deque(maxlen=QUEUE_WAIT_RECORD),
         repr=False, compare=False)
